@@ -23,6 +23,9 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         convergence,
         kernels,

@@ -80,6 +80,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import smbgd as smbgd_lib
 from repro.core.easi import EASIConfig
 from repro.core.smbgd import SMBGDConfig
@@ -321,7 +322,7 @@ def autotune_bank(
     keyed by ``(S, P, m, n, backend)``) where ``SeparatorBank`` picks it up
     by default; ``dtype_policy`` numbers are recorded but never auto-applied.
     Interpret-mode numbers steer nothing on real hardware — the cache key's
-    backend tag keeps them apart (run with REPRO_PALLAS_INTERPRET=0 on TPU).
+    backend tag keeps them apart (the lowering follows the backend).
     """
     ecfg = EASIConfig(n_components=n, n_features=m, mu=1e-3)
     ocfg = SMBGDConfig(batch_size=P, mu=1e-3, beta=0.9, gamma=0.5)
@@ -1716,6 +1717,7 @@ def main() -> None:
         "--out", default=str(DEFAULT_OUT), help="result file (JSON rows)"
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.record_trace:
         record_trace()
         sys.exit(0)

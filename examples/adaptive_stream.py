@@ -12,8 +12,8 @@ Part 2 (the production shape): a ``SeparatorBank`` runs S such sessions at
 once — every stream has its own sources, its own mixing matrix and its own
 drift phase (``MixedSignals(streams=S)``), yet each tick is ONE fused array
 program.  With ``use_pallas=True`` the gradient sums of all streams go through
-a single (streams, P-tiles) kernel launch (interpreted on CPU; set
-``REPRO_PALLAS_INTERPRET=0`` on real TPU hardware).
+a single (streams, P-tiles) kernel launch (interpreted on the CPU backend,
+compiled on a TPU).
 
 Part 3 (the serving shape): more sessions than slots.  A ``SeparationService``
 with a ``ConvergencePolicy`` watches each session's in-bank convergence

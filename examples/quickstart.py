@@ -9,8 +9,8 @@ the SGD-vs-SMBGD comparison on the same problem.
 
 ``AdaptiveICA`` is the single-stream front-end (``algorithm`` selects
 ``sgd | smbgd_sequential | smbgd_batched``; ``use_pallas=True`` routes the
-gradient sum through the fused Pallas kernel — interpreted on CPU by default,
-set ``REPRO_PALLAS_INTERPRET=0`` on real TPU).  To run many separation
+gradient sum through the fused Pallas kernel — interpreted on the CPU
+backend, compiled on a TPU).  To run many separation
 sessions at once as one fused program, see ``repro.stream.SeparatorBank``
 (examples/adaptive_stream.py) and ``serve.engine.SeparationService``.
 """

@@ -59,8 +59,6 @@ def make_sharded_step(mesh, easi_cfg: EASIConfig, cfg: SMBGDConfig, axis: str = 
     sequential semantics match the single-device run when samples are
     contiguously sharded.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     P_global = cfg.batch_size
     if P_global % n_shards:
@@ -77,12 +75,12 @@ def make_sharded_step(mesh, easi_cfg: EASIConfig, cfg: SMBGDConfig, axis: str = 
     def step(state: SMBGDState, X_batch: jnp.ndarray):
         w = cfg.within_batch_weights(dtype=state.B.dtype)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_gradient,
             mesh=mesh,
             in_specs=(P(None, None), P(axis, None), P(axis)),
             out_specs=(P(None, None), P(axis, None)),
-            check_rep=False,
+            check_vma=False,
         )
         S, Y = sharded(state.B, X_batch, w)
         H_hat, B_next = smbgd_lib.smbgd_commit(

@@ -158,7 +158,13 @@ class MixedSignals:
 
     # per-stream seed/drift-phase derivation (stream=None → legacy stream)
     def _stream_seed(self, stream: Optional[int]) -> int:
-        return self.seed if stream is None else self.seed + 1_000_003 * (stream + 1)
+        # reduced modulo the Mersenne prime 2^31 - 1 so every seed fits the
+        # int32 JAX traces it as (unreduced, stream 2146 of seed 0 overflows);
+        # the multiplier is invertible mod a prime, so seeds never collide,
+        # and streams below the overflow keep their seeds
+        if stream is None:
+            return self.seed
+        return (self.seed + 1_000_003 * (stream + 1)) % (2**31 - 1)
 
     def _drift_phase(self, stream: Optional[int]) -> float:
         # golden-angle stagger so concurrent streams never drift in phase

@@ -87,6 +87,18 @@ def _source_batch_jit(pipe: MixedSignals, seed, A, phase, step) -> jnp.ndarray:
     return pipe._stream_batch(seed, A, phase, step)
 
 
+@functools.lru_cache(maxsize=1)
+def _host_device() -> Optional[jax.Device]:
+    """The CPU device ``SyntheticSource`` generates on: a source is a host
+    feed, and on an accelerator every block made there would cost a launch
+    and a device-to-host copy per session per tick.  ``None`` (JAX's default
+    device) when the process has no CPU backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 def _givens(m: int, theta) -> jnp.ndarray:
     """Rotation by ``theta`` in the (0, 1) plane of R^m — the same plane
     ``MixedSignals._drift`` and ``signals.drifting_mixing_matrix`` rotate."""
@@ -133,7 +145,8 @@ class SyntheticSource:
         self.drift_stop = None if drift_stop is None else int(drift_stop)
         self._seed = pipe._stream_seed(stream)
         self._phase = pipe._drift_phase(stream)
-        self._A0 = pipe._base_mixing(self._seed)
+        with jax.default_device(_host_device()):
+            self._A0 = pipe._base_mixing(self._seed)
         self._step = 0
         # one trace for every block: (seed, A_eff, phase, step) are traced,
         # the stationary-pipe shape knobs come from the frozen dataclass —
@@ -177,7 +190,8 @@ class SyntheticSource:
             * self.pipe.batch
             + self._phase
         )
-        return _givens(self.pipe.m, theta) @ self._A0
+        with jax.default_device(_host_device()):
+            return _givens(self.pipe.m, theta) @ self._A0
 
     def true_mixing(self) -> np.ndarray:
         """Ground-truth mixing at the CURRENT cursor ``(m, n)``."""
@@ -191,7 +205,8 @@ class SyntheticSource:
                 f"n_samples={n_samples}"
             )
         A = self.mixing_at(self._step)
-        X = self._gen(self._seed, A, self._phase, self._step)  # (P, m)
+        with jax.default_device(_host_device()):
+            X = self._gen(self._seed, A, self._phase, self._step)  # (P, m)
         self._step += 1
         return np.asarray(X, dtype=np.float32).T
 

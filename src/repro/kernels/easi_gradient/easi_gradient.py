@@ -24,7 +24,7 @@ same ``(streams, P-tiles)`` grid, but each grid step also computes its tile of
 write), and each stream's LAST tile performs the SMBGD commit in-register:
 
 ``prefetch=True`` swaps the X operand's block pipeline for an explicit
-double-buffered DMA: X stays in ``pltpu.ANY`` (HBM on TPU) and the kernel
+double-buffered DMA: X stays in ``pl.ANY`` (HBM on TPU) and the kernel
 overlaps the NEXT tile's ``make_async_copy`` with the CURRENT tile's gradient
 fold — the paper's "compute never waits on memory" pipelining one level
 deeper than BlockSpec auto-pipelining, with the prefetch window crossing
@@ -39,6 +39,8 @@ output ref's dtype at commit, so a bank whose ``B``/``Ĥ`` live in bf16 (see
 ``ops.BankLayout.dtype_policy``) runs the gradient fold and the commit
 accumulation entirely in f32 — casts happen ONLY at the load/commit
 boundaries, and frozen (inactive) slots round-trip bf16→f32→bf16 exactly.
+Every contraction runs at ``Precision.HIGHEST`` (``_dot``): a TPU otherwise
+rounds f32 matmul operands to bf16, and the paper's datapath is f32.
 
     Ĥ' = γ̂·Ĥ + Σ_tiles S_tile      (γ̂ gated to 0 where step == 0)
     B' = B + Ĥ'·B ;  step' = step + 1
@@ -68,9 +70,12 @@ Layout notes (TPU target; validated on CPU via interpret=True):
     innermost, so it is re-initialized at each stream's tile 0 and consumed by
     the commit at tile T-1; ``B``/``Ĥ`` blocks are revisited (index map pins
     them per stream) and written once, on the commit tile,
-  * per-stream scalars (``step``, ``γ̂``, ``active``) ride as (1, 1) blocks —
-    on real TPU these are natural SMEM residents; interpret mode does not
-    distinguish,
+  * per-stream scalars (``step``, ``γ̂``, ``active``, ``conv`` and the
+    health/moment outputs) ride as ``(block_s, 1)`` / ``(block_s, 2)`` VMEM
+    blocks, so Mosaic's sublane rule requires ``block_s`` to be a multiple
+    of 8 or the whole bank (``ops.default_block_s`` picks only those),
+  * every per-stream reduction keeps its rank (``_reduce_rows``): Mosaic
+    aborts on a two-axis reduction to a rank-1 vector,
   * zero padding is exact end-to-end: padded m-columns of X/B keep padded Y
     zero (g(0) = 0 for every registered nonlinearity), padded w rows add
     nothing, and the only nonzero the commit writes into the padded region is
@@ -124,6 +129,37 @@ HEALTH_BLOWUP_BOUND = 100.0
 # padded Y entries are exactly zero and contribute nothing to either sum.
 MOMENT_LEAVES = 2  # [Σy², Σy⁴]
 
+# Scoped VMEM the step and probe megakernels may use (Mosaic's
+# ``vmem_limit_bytes``).  TPU v5e holds 128 MiB of VMEM but its compiler
+# scopes a kernel to 16 MiB unless told otherwise.  64 MiB lets a bank of 36
+# paper-shape sessions, a width that is not a multiple of 8, run as one
+# stream-block, and a ``block_p=512`` layout (about 5.2 MiB per stream at
+# HIGHEST precision) still take 8 streams per block.  ``ops.default_block_s``
+# budgets against this number.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _reduce_rows(op, a):
+    """Reduce a ``(bs, r, c)`` block to ``(bs, 1)`` with ``op`` (``jnp.sum``
+    or ``jnp.any``): lanes first, then sublanes, each with ``keepdims``.  The
+    one-shot ``op(a, axis=(1, 2))[:, None]`` form aborts the TPU compiler
+    (Mosaic's layout check on the rank-1 intermediate), so every per-stream
+    reduction in these kernels goes through here — and the vmap reference
+    (``stream.bank``) reduces in the same order."""
+    return op(op(a, axis=2, keepdims=True), axis=1)
+
+
+def _dot(a, b, dims):
+    """f32 ``dot_general`` at full precision.  On a TPU the default rounds
+    f32 operands to bf16, and the rounding grows fast in a separator near
+    its blow-up bound.  HIGHEST costs VMEM for the split operands, which
+    ``ops._resident_bytes_per_stream`` counts; the interpreter computes in
+    f32 either way."""
+    return jax.lax.dot_general(
+        a, b, dims, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
 
 def _health_word(b_new, h_new, ybad, delta, blowup: float):
     """Fold the per-stream health bitmask from commit-time registers:
@@ -131,8 +167,8 @@ def _health_word(b_new, h_new, ybad, delta, blowup: float):
     some Y tile was non-finite), ``delta`` (bs, 1) the conv statistic.
     ``~(delta <= blowup)`` deliberately catches NaN deltas too."""
     i32 = jnp.int32
-    bbad = jnp.any(~jnp.isfinite(b_new), axis=(1, 2))[:, None]
-    hbad = jnp.any(~jnp.isfinite(h_new), axis=(1, 2))[:, None]
+    bbad = _reduce_rows(jnp.any, ~jnp.isfinite(b_new))
+    hbad = _reduce_rows(jnp.any, ~jnp.isfinite(h_new))
     blow = ~(delta <= blowup)
     return (
         bbad.astype(i32) * HEALTH_NONFINITE_B
@@ -147,12 +183,8 @@ def _fold_tile(y, w, nonlin: str):
     g = NONLIN_KERNELS[nonlin](y)
     yw = y * w  # weighted rows — one VPU pass
     # Two MXU contractions over the tile's P dimension (rank-bp updates).
-    gram = jax.lax.dot_general(
-        y, yw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # Yᵀ W Y  (n, n)
-    cross = jax.lax.dot_general(
-        g, yw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # Gᵀ W Y  (n, n)
+    gram = _dot(y, yw, (((0,), (0,)), ((), ())))  # Yᵀ W Y  (n, n)
+    cross = _dot(g, yw, (((0,), (0,)), ((), ())))  # Gᵀ W Y  (n, n)
     n = gram.shape[0]
     # Per-tile identity contribution: Σ_tiles sum(w_tile)·I == sum(w)·I overall.
     eye = jnp.eye(n, dtype=jnp.float32) * jnp.sum(w)
@@ -256,8 +288,8 @@ def _fold_tile_batched(y, w, nonlin: str):
     g = NONLIN_KERNELS[nonlin](y)
     yw = y * w  # (bs, bp, n) * (bs, bp, 1)
     dims = (((1,), (1,)), ((0,), (0,)))  # contract bp, batch over streams
-    gram = jax.lax.dot_general(y, yw, dims, preferred_element_type=jnp.float32)
-    cross = jax.lax.dot_general(g, yw, dims, preferred_element_type=jnp.float32)
+    gram = _dot(y, yw, dims)
+    cross = _dot(g, yw, dims)
     n = gram.shape[-1]
     eye = jnp.eye(n, dtype=jnp.float32)[None] * jnp.sum(w, axis=1, keepdims=True)
     return eye - gram - cross + cross.transpose(0, 2, 1)
@@ -309,17 +341,14 @@ def _commit_streams(
     gamma_hat = jnp.where(step == 0, 0.0, gamma_hat_ref[...])[:, :, None]
     h_prev = h_ref[...].astype(jnp.float32)  # (bs, n, n)
     h_new = gamma_hat * h_prev + acc_ref[...]
-    db = jax.lax.dot_general(
-        h_new, b, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # ΔB = Ĥ′B (bs, n, m)
+    db = _dot(h_new, b, (((2,), (1,)), ((0,), (0,))))  # ΔB = Ĥ′B (bs, n, m)
     b_new = b + db
     # per-stream convergence statistic ‖ΔB‖_F / ‖B‖_F, in-register — no
     # extra HBM round-trip.  Padding-exact: padded rows/cols of B are
     # zero, so the padded Σw diagonal of Ĥ′ never reaches ΔB.
-    num = jnp.sqrt(jnp.sum(db * db, axis=(1, 2)))  # (bs,)
-    den = jnp.sqrt(jnp.sum(b * b, axis=(1, 2)))
-    delta = (num / jnp.maximum(den, 1e-12))[:, None]  # (bs, 1)
+    num = jnp.sqrt(_reduce_rows(jnp.sum, db * db))  # (bs, 1)
+    den = jnp.sqrt(_reduce_rows(jnp.sum, b * b))
+    delta = num / jnp.maximum(den, 1e-12)  # (bs, 1)
     conv_prev = conv_ref[...].astype(jnp.float32)  # (bs, 1)
     if with_health:
         health = _health_word(b_new, h_new, ybad_ref[...], delta, blowup)
@@ -351,7 +380,7 @@ def _fold_ybad_tile(y, ybad_ref, i, with_health: bool):
     # Σ(y·0) is NaN iff the tile holds any non-finite (Inf·0 = NaN·0 = NaN)
     # and exactly 0 otherwise — no finite-overflow corner, and one multiply +
     # one reduction instead of the isfinite/not/any triple pass.
-    marker = jnp.sum(y * 0.0, axis=(1, 2))[:, None]
+    marker = _reduce_rows(jnp.sum, y * 0.0)  # (bs, 1)
     ybad = (~(marker == 0.0)).astype(jnp.int32)
 
     @pl.when(i == 0)
@@ -371,8 +400,8 @@ def _fold_moment_tile(y, mom_ref, i, with_moments: bool):
     if not with_moments:
         return
     y2 = y * y  # one VPU square; y⁴ = (y²)² reuses it
-    mom = jnp.stack(
-        [jnp.sum(y2, axis=(1, 2)), jnp.sum(y2 * y2, axis=(1, 2))], axis=-1
+    mom = jnp.concatenate(
+        [_reduce_rows(jnp.sum, y2), _reduce_rows(jnp.sum, y2 * y2)], axis=-1
     )  # (bs, 2)
 
     @pl.when(i == 0)
@@ -421,9 +450,8 @@ def _smbgd_step_bank_kernel(
     i = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)  # (bs, bp, m)
     b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = jax.lax.dot_general(
-        x, b, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # (bs, bp, n) — these streams' Y tiles, never re-read from HBM
+    # (bs, bp, n) — these streams' Y tiles, never re-read from HBM
+    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
     y_ref[...] = y.astype(y_ref.dtype)
     w = w_ref[...].astype(jnp.float32)  # (bs, bp, 1) — per-stream weight rows
     s_tile = _fold_tile_batched(y, w, nonlin)
@@ -494,7 +522,7 @@ def _smbgd_step_bank_kernel_prefetch(
     blowup: float,
 ):
     """Double-buffered variant of ``_smbgd_step_bank_kernel``: X rides in
-    ``pltpu.ANY`` (HBM) and each grid step starts the NEXT tile's DMA before
+    ``pl.ANY`` (HBM) and each grid step starts the NEXT tile's DMA before
     folding the CURRENT tile, alternating two VMEM buffers.  The prefetch
     window runs over the GLOBAL tile counter ``t = sb·n_tiles + i``, so it
     crosses stream-block boundaries — only tile 0 of the whole launch pays an
@@ -521,9 +549,7 @@ def _smbgd_step_bank_kernel_prefetch(
     dma(jax.lax.rem(t, 2), t).wait()
     x = xbuf_ref[jax.lax.rem(t, 2)].astype(jnp.float32)  # (bs, bp, m)
     b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = jax.lax.dot_general(
-        x, b, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
+    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
     y_ref[...] = y.astype(y_ref.dtype)
     w = w_ref[...].astype(jnp.float32)
     s_tile = _fold_tile_batched(y, w, nonlin)
@@ -580,9 +606,8 @@ def _smbgd_probe_bank_kernel(
     i = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)  # (bs, bp, m)
     b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = jax.lax.dot_general(
-        x, b, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # (bs, bp, n) — stays in VMEM; probes never publish Y
+    # (bs, bp, n) — stays in VMEM; probes never publish Y
+    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
     w = w_ref[...].astype(jnp.float32)  # (bs, bp, 1)
     s_tile = _fold_tile_batched(y, w, nonlin)
     _fold_ybad_tile(y, ybad_ref, i, with_health)
@@ -634,13 +659,11 @@ def _probe_streams(
     active = active_ref[...] != 0  # (bs, 1)
     gamma_hat = jnp.where(step == 0, 0.0, gamma_hat_ref[...])[:, :, None]
     h_new = gamma_hat * h_ref[...].astype(jnp.float32) + acc_ref[...]
-    db = jax.lax.dot_general(
-        h_new, b, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # virtual ΔB = Ĥ′B (bs, n, m) — computed, never committed
-    num = jnp.sqrt(jnp.sum(db * db, axis=(1, 2)))  # (bs,)
-    den = jnp.sqrt(jnp.sum(b * b, axis=(1, 2)))
-    delta = (num / jnp.maximum(den, 1e-12))[:, None]  # (bs, 1)
+    # virtual ΔB = Ĥ′B (bs, n, m) — computed, never committed
+    db = _dot(h_new, b, (((2,), (1,)), ((0,), (0,))))
+    num = jnp.sqrt(_reduce_rows(jnp.sum, db * db))  # (bs, 1)
+    den = jnp.sqrt(_reduce_rows(jnp.sum, b * b))
+    delta = num / jnp.maximum(den, 1e-12)  # (bs, 1)
     conv_prev = conv_ref[...].astype(jnp.float32)
     if with_health:
         health = _health_word(b + db, h_new, ybad_ref[...], delta, blowup)
@@ -705,9 +728,7 @@ def _smbgd_probe_bank_kernel_prefetch(
     dma(jax.lax.rem(t, 2), t).wait()
     x = xbuf_ref[jax.lax.rem(t, 2)].astype(jnp.float32)  # (bs, bp, m)
     b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = jax.lax.dot_general(
-        x, b, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
+    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
     w = w_ref[...].astype(jnp.float32)
     s_tile = _fold_tile_batched(y, w, nonlin)
     _fold_ybad_tile(y, ybad_ref, i, with_health)
@@ -787,7 +808,7 @@ def smbgd_probe_bank_pallas(
             block_s=bs, block_p=block_p, with_health=health,
             with_moments=moments, blowup=blowup,
         )
-        x_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [
             pltpu.VMEM((bs, n, n), jnp.float32),
             pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
@@ -795,7 +816,6 @@ def smbgd_probe_bank_pallas(
             pltpu.VMEM((2, bs, block_p, m), X.dtype),  # the double buffer
             pltpu.SemaphoreType.DMA((2,)),
         ]
-        extra = _prefetch_call_params()
     else:
         kernel = functools.partial(
             _smbgd_probe_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
@@ -807,7 +827,6 @@ def smbgd_probe_bank_pallas(
             pltpu.VMEM((bs, 1), jnp.int32),
             pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),
         ]
-        extra = {}
     return pl.pallas_call(
         kernel,
         grid=(n_sblocks, n_tiles),
@@ -824,23 +843,21 @@ def smbgd_probe_bank_pallas(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-        **extra,
+        **_compiler_params(prefetch),
     )(X, W, B, H_hat, step, gamma_hat, active, conv)
 
 
-def _prefetch_call_params() -> dict:
-    """Extra ``pallas_call`` kwargs for the prefetch kernels: the global-tile
-    prefetch window threads DMA state across grid cells, so BOTH grid
-    dimensions must execute sequentially on real TPU ("arbitrary", never
-    "parallel" — Mosaic must not megacore-split the grid).  Interpret mode
-    executes sequentially anyway; older JAX without ``TPUCompilerParams``
-    just omits the hint (interpret-only environments)."""
-    params = getattr(pltpu, "TPUCompilerParams", None)
-    if params is None:
-        return {}
+def _compiler_params(prefetch: bool) -> dict:
+    """``pallas_call`` kwargs shared by the step and probe megakernels: the
+    explicit VMEM limit the default ``block_s`` is budgeted against
+    (``ops.default_block_s``), and — for the prefetch kernels, whose
+    global-tile prefetch window threads DMA state across grid cells — BOTH
+    grid dimensions sequential ("arbitrary", never "parallel": Mosaic must
+    not megacore-split the grid).  The interpreter ignores both."""
     return {
-        "compiler_params": params(
-            dimension_semantics=("arbitrary", "arbitrary")
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary") if prefetch else None,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         )
     }
 
@@ -876,7 +893,7 @@ def smbgd_step_bank_pallas(
     independent, so the result is block_s invariant; larger blocks amortize
     per-cell grid overhead.  ``prefetch=True`` replaces the X BlockSpec
     pipeline with an explicit double-buffered ``make_async_copy`` from
-    ``pltpu.ANY`` — overlapping the next tile's DMA with the current fold —
+    ``pl.ANY`` — overlapping the next tile's DMA with the current fold —
     and is bit-identical on the interpret path (tested).  ``B``/``H_hat``
     may live in a reduced-precision storage dtype (bf16): the kernel casts
     to f32 at load, accumulates the gradient and the commit in f32, and
@@ -917,7 +934,7 @@ def smbgd_step_bank_pallas(
             block_s=bs, block_p=block_p, with_health=health,
             with_moments=moments, blowup=blowup,
         )
-        x_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [
             pltpu.VMEM((bs, n, n), jnp.float32),
             pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
@@ -925,7 +942,6 @@ def smbgd_step_bank_pallas(
             pltpu.VMEM((2, bs, block_p, m), X.dtype),  # the double buffer
             pltpu.SemaphoreType.DMA((2,)),
         ]
-        extra = _prefetch_call_params()
     else:
         kernel = functools.partial(
             _smbgd_step_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
@@ -937,7 +953,6 @@ def smbgd_step_bank_pallas(
             pltpu.VMEM((bs, 1), jnp.int32),
             pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),
         ]
-        extra = {}
     return pl.pallas_call(
         kernel,
         grid=(n_sblocks, n_tiles),
@@ -962,5 +977,5 @@ def smbgd_step_bank_pallas(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-        **extra,
+        **_compiler_params(prefetch),
     )(X, W, B, H_hat, step, gamma_hat, active, conv)

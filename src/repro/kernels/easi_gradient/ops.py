@@ -1,10 +1,9 @@
 """Jit'd public wrappers for the EASI-gradient kernels: padding, alignment,
-dtype policy and the interpret-mode switch.
+dtype policy and the block geometry.
 
-``REPRO_PALLAS_INTERPRET`` controls lowering: the default (``1``) runs the
-kernels through the Pallas interpreter so the CPU container can execute and
-test them; on real TPU set ``REPRO_PALLAS_INTERPRET=0`` to compile to Mosaic.
-Both entry points honour it:
+Lowering follows the backend (``repro.kernels.interpret_mode``): the Pallas
+interpreter on the CPU, Mosaic on a TPU.  Every entry point takes an explicit
+``interpret=`` override for tests:
 
   * ``easi_gradient``       — single stream,   ``Y (P, n)``    → ``S (n, n)``
   * ``easi_gradient_bank``  — S streams fused, ``Y (S, P, n)`` → ``S (S, n, n)``
@@ -31,8 +30,9 @@ Memory-system knobs (PR 6):
     bf16 (f32 accumulation inside the kernels) — ``BankLayout`` owns the
     byte accounting (``persistent_bytes_per_session``,
     ``tick_hbm_bytes_per_stream``),
-  * the default ``block_s`` is derived from the layout's actual VMEM
-    residency against a budget (``default_block_s``), not a hardcoded cap.
+  * the default ``block_s`` is derived from the layout's VMEM residency
+    against the kernels' VMEM limit (``default_block_s``), not a hardcoded
+    cap.
 """
 from __future__ import annotations
 
@@ -43,6 +43,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.easi_gradient.easi_gradient import (  # noqa: F401 — health
     HEALTH_BLOWUP,  # constants re-exported: ops is the public kernel surface
     HEALTH_BLOWUP_BOUND,
@@ -51,6 +52,7 @@ from repro.kernels.easi_gradient.easi_gradient import (  # noqa: F401 — health
     HEALTH_NONFINITE_Y,
     HEALTH_OK,
     MOMENT_LEAVES,
+    VMEM_LIMIT_BYTES,
     easi_gradient_bank_pallas,
     easi_gradient_pallas,
     smbgd_probe_bank_pallas,
@@ -99,16 +101,16 @@ _SUBLANE = 8  # f32 sublane
 STORAGE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 
 # VMEM budget for the default block_s derivation: resident bytes per stream x
-# block_s must fit.  Compiled kernels get half of a 16 MiB TPU VMEM (the
-# other half is headroom for Mosaic's own pipeline buffers); the interpreter
-# has no VMEM but the same accounting bounds its per-cell host temporaries.
+# block_s must fit.  Compiled megakernels run under an explicit Mosaic
+# ``vmem_limit_bytes`` of 64 MiB (``easi_gradient.VMEM_LIMIT_BYTES``; TPU
+# v5e's compiler otherwise scopes kernels to 16 MiB of its 128 MiB VMEM, as
+# its out-of-memory report states).  ``_resident_bytes_per_stream``
+# over-counts what Mosaic allocates, so the whole limit is the budget.  The
+# interpreter has no VMEM; the same accounting bounds its per-cell host
+# temporaries.
 _VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET_BYTES"
-_DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+_DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES
 _DEFAULT_INTERPRET_BUDGET = 64 * 1024 * 1024
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -175,14 +177,13 @@ class BankLayout:
         state_bytes = 2 * (self.n_pad * self.m_pad + self.n_pad * self.n_pad) * itemsize
         return x_bytes + w_bytes + y_bytes + state_bytes + 4 * 2 + 4 * 2
 
-    def vmem_resident_bytes_per_stream(self, prefetch: bool = False) -> int:
+    def vmem_resident_bytes_per_stream(self) -> int:
         """Conservative per-stream VMEM residency of one megakernel grid
         cell — what the default ``block_s`` derivation budgets against."""
         return _resident_bytes_per_stream(
             self.block_p, self.n_pad, self.m_pad,
             x_itemsize=4,
             state_itemsize=jnp.dtype(self.storage_dtype).itemsize,
-            prefetch=prefetch,
         )
 
 
@@ -203,7 +204,7 @@ def bank_layout(
     (see ``BankLayout``).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     if dtype_policy not in STORAGE_DTYPES:
         raise ValueError(
             f"dtype_policy must be one of {sorted(STORAGE_DTYPES)}, "
@@ -234,7 +235,7 @@ def easi_gradient(
     satisfy g(0)=0, which the padding relies on (asserted in tests).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     P, n = Y.shape
     P_pad, n_pad, block_p = _pad_geometry(P, n, block_p, interpret)
     if (P_pad, n_pad) == (P, n):  # block-aligned: no staging copy
@@ -267,7 +268,7 @@ def easi_gradient_bank(
     bit-identical to a single-stream launch with the same block geometry.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     S_streams, P, n = Y.shape
     P_pad, n_pad, block_p = _pad_geometry(P, n, block_p, interpret)
     if (P_pad, n_pad) == (P, n):  # block-aligned: no per-step staging copy
@@ -289,19 +290,39 @@ def _resident_bytes_per_stream(
     *,
     x_itemsize: int = 4,
     state_itemsize: int = 4,
-    prefetch: bool = False,
 ) -> int:
-    """Conservative VMEM bytes ONE stream keeps resident in a megakernel grid
-    cell: the X tile (doubled when prefetch double-buffers it), the W rows,
-    B/Ĥ in+out blocks at the storage dtype, the f32 gradient accumulator, the
-    Y output tile, and the f32 tile-fold temporaries (y, g, y·w)."""
-    x_bytes = block_p * m_pad * x_itemsize * (2 if prefetch else 1)
-    w_bytes = block_p * 4
-    state_bytes = 2 * (n_pad * m_pad + n_pad * n_pad) * state_itemsize
-    acc_bytes = n_pad * n_pad * 4
-    y_bytes = block_p * n_pad * x_itemsize
-    tmp_bytes = 3 * block_p * n_pad * 4
-    return x_bytes + w_bytes + state_bytes + acc_bytes + y_bytes + tmp_bytes
+    """Conservative VMEM bytes ONE stream holds in a step-megakernel grid
+    cell (the probe kernel holds less):
+
+      * every pipelined block twice (Pallas double-buffers them): the X tile
+        (the prefetch kernel's manual double buffer is the same two tiles),
+        the W rows (a ``(block_p, 1)`` block is lane-padded to 128), the Y
+        tile, ``B``/``Ĥ`` in and ``B′``/``Ĥ′`` out at the storage dtype, and
+        eight ``(S, 1)``/``(S, 2)`` side-channel rows (lane-padded, 512 B
+        per stream each);
+      * the f32 gradient accumulator;
+      * the f32 working copies of ``B`` and ``Ĥ`` the commit holds — twice
+        when the storage dtype is narrower, since the kernel upcasts both
+        and holds ``Ĥ′``/``ΔB``/``B′`` in f32 before the downcast;
+      * three f32-sized copies of every contraction operand, for the
+        HIGHEST-precision matmuls (``easi_gradient._dot``) split each f32
+        operand into bf16 parts: the X tile and ``B`` (Y), Y, G and W·Y
+        (the gradient fold), ``Ĥ′`` and ``B`` (the commit).
+
+    Compiled on TPU v5e at ``m=4, n=2`` (128-lane padding) Mosaic allocated
+    about 1,044 KiB per stream for f32 state and 768 KiB for bf16 at
+    ``block_p=8``, and 5,308 KiB at ``block_p=512``; this count gives
+    1,360, 1,232 and 5,896 KiB."""
+    lane_row = _LANE * 4
+    rows = 2 * block_p * (m_pad * x_itemsize + lane_row + n_pad * x_itemsize)
+    side = 2 * 8 * lane_row
+    logical_state = n_pad * m_pad + n_pad * n_pad
+    state = 2 * 2 * logical_state * state_itemsize
+    acc = n_pad * n_pad * 4
+    working = logical_state * 4 * (1 if state_itemsize >= 4 else 2)
+    operands = block_p * (m_pad + 3 * n_pad) + 2 * n_pad * m_pad + n_pad * n_pad
+    dots = 3 * 4 * operands
+    return rows + side + state + acc + working + dots
 
 
 def vmem_budget_bytes(interpret: bool) -> int:
@@ -317,26 +338,35 @@ def vmem_budget_bytes(interpret: bool) -> int:
 def _default_block_s(
     S: int, *, resident_bytes: int, interpret: bool
 ) -> int:
-    """Largest divisor of S whose stream-block fits the VMEM budget —
-    ``resident_bytes × block_s ≤ vmem_budget_bytes()``.  Streams batched per
-    grid cell amortize per-cell launch overhead (and, in interpret mode, the
-    per-cell grid-loop cost); per-stream math is independent so any divisor
-    is numerically equivalent (tested).  Deriving the cap from the layout's
-    actual residency (instead of a hardcoded 8/32) means large ``(m, n)``
-    shapes shrink ``block_s`` instead of silently blowing VMEM — and a shape
-    whose SINGLE stream exceeds the budget fails loudly on compiled backends
-    (the interpreter clamps to 1: no VMEM to blow, only host memory)."""
+    """Largest stream-block whose residency fits the VMEM budget —
+    ``resident_bytes × block_s ≤ vmem_budget_bytes()`` — among the widths
+    Mosaic can tile: ``S`` itself or a divisor of ``S`` that is a multiple
+    of 8 (the per-stream ``(block_s, 1)`` side-channel blocks are bound by
+    the sublane rule).  Streams batched per grid cell amortize per-cell
+    launch overhead; per-stream math is independent so any block is
+    numerically equivalent (tested).  Deriving the cap from the layout's
+    residency (instead of a hardcoded 8/32) means large ``(m, n)`` shapes
+    shrink ``block_s`` instead of silently blowing VMEM.  A width with no
+    such block that fits fails loudly on compiled backends; the interpreter
+    has no tiling rule and no VMEM, so it falls back to any divisor (and
+    clamps to 1)."""
     budget = vmem_budget_bytes(interpret)
     cap = budget // max(resident_bytes, 1)
-    if cap < 1:
-        if not interpret:
-            raise ValueError(
-                f"one stream's megakernel residency ({resident_bytes} bytes) "
-                f"exceeds the VMEM budget ({budget} bytes) — shrink block_p "
-                f"or raise {_VMEM_BUDGET_ENV}"
-            )
-        cap = 1
-    for bs in range(min(S, cap), 0, -1):
+    tiled = [
+        bs for bs in range(min(S, cap), 0, -1)
+        if S % bs == 0 and (bs == S or bs % _SUBLANE == 0)
+    ]
+    if tiled:
+        return tiled[0]
+    if not interpret:
+        raise ValueError(
+            f"every tileable stream-block of a {S}-stream bank exceeds the "
+            f"VMEM budget ({budget} bytes at {resident_bytes} bytes per "
+            f"stream; the block must be {S} or a multiple of {_SUBLANE} "
+            f"dividing it) — use a width that is a multiple of {_SUBLANE}, "
+            f"shrink block_p, or raise {_VMEM_BUDGET_ENV}"
+        )
+    for bs in range(min(S, max(cap, 1)), 0, -1):
         if S % bs == 0:
             return bs
     return 1
@@ -346,17 +376,16 @@ def default_block_s(
     S: int,
     layout: BankLayout,
     *,
-    prefetch: bool = False,
     interpret: bool | None = None,
 ) -> int:
     """Public form of the default ``block_s`` derivation for a layout —
     what ``smbgd_step_bank`` resolves when ``block_s=None`` (benchmarks and
     tests use this to predict/verify the resolution)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     return _default_block_s(
         S,
-        resident_bytes=layout.vmem_resident_bytes_per_stream(prefetch),
+        resident_bytes=layout.vmem_resident_bytes_per_stream(),
         interpret=interpret,
     )
 
@@ -422,7 +451,7 @@ def smbgd_step_bank(
     ``benchmarks --health``).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     S_streams, P_pad, m_pad = X.shape
     n_pad = B.shape[1]
     if block_p is None:
@@ -434,7 +463,6 @@ def smbgd_step_bank(
                 block_p, n_pad, m_pad,
                 x_itemsize=X.dtype.itemsize,
                 state_itemsize=B.dtype.itemsize,
-                prefetch=prefetch,
             ),
             interpret=interpret,
         )
@@ -526,7 +554,7 @@ def smbgd_probe_bank(
     separators, one launch per ``S``-wide probe batch.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     S_streams, P_pad, m_pad = X.shape
     n_pad = B.shape[1]
     if block_p is None:
@@ -538,7 +566,6 @@ def smbgd_probe_bank(
                 block_p, n_pad, m_pad,
                 x_itemsize=X.dtype.itemsize,
                 state_itemsize=B.dtype.itemsize,
-                prefetch=prefetch,
             ),
             interpret=interpret,
         )

@@ -12,12 +12,9 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _use_pallas_default() -> bool:
@@ -61,5 +58,5 @@ def attention(
     return flash_attention_pallas(
         q, k, v,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, interpret=_interpret_default(),
+        block_q=block_q, block_k=block_k, interpret=interpret_mode(),
     )

@@ -2,19 +2,15 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.smbgd_update.smbgd_update import smbgd_update_pallas
 
 _LANE = 128
 _SUBLANE = 8
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -36,7 +32,7 @@ def smbgd_update(
     padded block of B is zero so Ĥ·B contributes nothing outside [:n, :m].
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n, m = B.shape
     align = _SUBLANE if interpret else _LANE
     n_pad = _round_up(max(n, _SUBLANE), align)

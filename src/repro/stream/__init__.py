@@ -23,8 +23,8 @@ Public API:
   * ``make_sharded_bank_step`` / ``bank_sharding`` — stream-axis device
     parallelism (streams are independent: no collectives in the hot path).
 
-Pallas kernels run through the interpreter by default so the CPU container can
-execute them; set ``REPRO_PALLAS_INTERPRET=0`` on real TPU hardware.
+Pallas kernels run through the interpreter on the CPU backend and compile to
+Mosaic on a TPU (``repro.kernels.interpret_mode``).
 """
 from repro.core.smbgd import BankHyperparams
 from repro.stream.bank import BankState, SeparatorBank

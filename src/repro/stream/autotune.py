@@ -36,6 +36,8 @@ from typing import Dict, Optional
 
 import jax
 
+from repro.kernels import interpret_mode
+
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 _DEFAULT_PATH = Path(__file__).resolve().parents[3] / "AUTOTUNE.json"
 
@@ -57,7 +59,7 @@ def backend_tag(interpret: Optional[bool] = None) -> str:
     """Backend half of the cache key: tuned numbers never steer a different
     lowering (interpret-mode timings are meaningless on real TPU)."""
     if interpret is None:
-        interpret = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+        interpret = interpret_mode()
     return f"{jax.default_backend()}{'-interpret' if interpret else ''}"
 
 

@@ -208,8 +208,9 @@ class SeparatorBank:
     state (requires ``algorithm="smbgd_batched"``); ``block_p`` overrides the
     kernel's P-tile size (autotune knob; default picks ``min(512, P)``
     rounded to the sublane) and ``block_s`` the number of streams batched per
-    grid cell (must divide ``n_streams``; default: largest divisor whose
-    per-cell VMEM residency fits the budget — see ``ops.default_block_s``).
+    grid cell (must divide ``n_streams``; default: the largest block Mosaic
+    can tile — ``n_streams`` or a multiple of 8 — whose per-cell VMEM
+    residency fits the budget; see ``ops.default_block_s``).
 
     ``dtype_policy`` ("f32"/"bf16") sets the persistent storage dtype of
     ``B``/``Ĥ`` (accumulation stays f32 everywhere); the default ``None``
@@ -828,9 +829,10 @@ class SeparatorBank:
         megakernel's in-register reduction (``easi_gradient.HEALTH_*``):
         1 non-finite B′, 2 non-finite Ĥ′, 4 non-finite Y, 8 update magnitude
         above ``blowup`` (``~(δ <= bound)`` so a NaN δ counts as blow-up)."""
-        fin_b = jnp.all(jnp.isfinite(new_state.B), axis=(1, 2))
-        fin_h = jnp.all(jnp.isfinite(new_state.H_hat), axis=(1, 2))
-        fin_y = jnp.all(jnp.isfinite(Y), axis=(1, 2))
+        rows = lambda a: jnp.all(jnp.all(jnp.isfinite(a), axis=2), axis=1)
+        fin_b = rows(new_state.B)  # lanes, then sublanes: the kernel's order
+        fin_h = rows(new_state.H_hat)
+        fin_y = rows(Y)
         blow = ~(new_state.conv <= blowup)
         return (
             jnp.where(fin_b, 0, 1)
@@ -847,9 +849,9 @@ class SeparatorBank:
         if not self.moments:
             return jnp.zeros((Y.shape[0], 2), jnp.float32)
         y2 = Y.astype(jnp.float32) ** 2
-        mom = jnp.stack(
-            [jnp.sum(y2, axis=(1, 2)), jnp.sum(y2 * y2, axis=(1, 2))], axis=-1
-        )
+        # lanes first, then rows — the kernel's ``_reduce_rows`` order
+        rows = lambda a: jnp.sum(jnp.sum(a, axis=2), axis=1)
+        mom = jnp.stack([rows(y2), rows(y2 * y2)], axis=-1)
         return jnp.where(act[:, None], mom, 0.0)
 
     @staticmethod

@@ -63,8 +63,6 @@ def make_sharded_bank_step(
     state buffers (persistent-padded fused banks: zero steady-state allocs
     per device).
     """
-    from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.shape[axis]
     if bank.n_streams % n_dev:
         raise ValueError(
@@ -99,12 +97,12 @@ def make_sharded_bank_step(
         return st.B, st.H_hat, st.step, st.conv, st.health, st.moments, Y
 
     hp_spec = (P(axis),) * 3 if hetero else ()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), hp_spec),
         out_specs=(P(axis),) * 7,
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(

@@ -303,3 +303,45 @@ class TestChaosEndToEnd:
             if dup.status("q") in ("active", "queued"):
                 break
         assert dup.status("q") in ("active", "queued")
+
+
+def test_donated_state_serves_and_rolls_back_like_undonated(monkeypatch):
+    """Accelerators donate the bank state to the kernel on every tick; the
+    CPU backend opts out, so nothing else here runs that path.  Forced on,
+    a fused service with a health shadow and moment telemetry serves
+    bit-identically to an undonated one — through a state corruption and
+    its rollback — and never touches a donated buffer."""
+    ecfg = EASIConfig(n_components=2, n_features=4, mu=2e-3)
+    ocfg = SMBGDConfig(batch_size=8, mu=2e-3, beta=0.9, gamma=0.5)
+    pipe = MixedSignals(m=4, n=2, batch=8, seed=3, streams=3)
+
+    def serve(donate):
+        monkeypatch.setattr(
+            SeparatorBank, "_donate_default",
+            staticmethod(lambda d: donate if d is None else d),
+        )
+        bank = SeparatorBank(ecfg, ocfg, 4, fused=True, moments=True)
+        svc = SeparationService(bank, health_policy=HealthPolicy())
+        for s in range(3):
+            svc.admit(s, source=SyntheticSource(pipe, stream=s))
+        outs = []
+        for t in range(6):
+            if t == 3:
+                svc.state = bank.corrupt_slot(svc.state, svc.sessions[1])
+            before = svc.state.B
+            outs.append(svc.run_tick())
+            assert before.is_deleted() is donate
+        return svc, outs
+
+    ref, ref_outs = serve(False)
+    don, don_outs = serve(True)
+    assert [e.action for e in don.health_events] == ["rollback"]
+    for a, b in zip(ref_outs, don_outs):
+        assert a.keys() == b.keys()
+        for sid in a:
+            np.testing.assert_array_equal(np.asarray(a[sid]), np.asarray(b[sid]))
+    for leaf in ("B", "H_hat", "step", "conv", "health", "moments"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(ref.state, leaf)),
+            np.asarray(getattr(don.state, leaf)),
+        )

@@ -87,6 +87,22 @@ class TestMixedSignals:
             np.asarray(p.mixing_at(0)), np.asarray(p.mixing_at(999))
         )
 
+    def test_stream_seeds_fit_int32_at_bank_scale(self):
+        """4096 streams: every per-stream seed is distinct and traces as an
+        int32 (stream 2146 of seed 0 used to overflow), and the bank block of
+        the last stream is what that stream's own source generates."""
+        from repro.data.sources import SyntheticSource
+
+        p = MixedSignals(m=4, n=2, batch=8, seed=0, streams=4096)
+        seeds = [p._stream_seed(s) for s in range(4096)]
+        assert len(set(seeds)) == 4096
+        assert max(seeds) + 1 <= np.iinfo(np.int32).max  # PRNGKey(seed + 1)
+        np.testing.assert_allclose(
+            np.asarray(p.batch_for_step(0)[4095]),
+            SyntheticSource(p, stream=4095).next_block(8).T,
+            rtol=1e-6, atol=1e-6,
+        )
+
 
 class TestSignalBank:
     def test_sources_zero_mean_unit_var(self):

@@ -237,17 +237,24 @@ class TestVmemDerivedBlockS:
         assert easi_ops.default_block_s(8, lay, interpret=True) == 8
 
     def test_prefetch_residency_costs_block_s(self, monkeypatch):
-        """The double buffer's second X slot counts against the budget: at a
-        budget sized to exactly fit the sync residency, prefetch shrinks the
-        derived block_s."""
-        lay = easi_ops.bank_layout(2, 4, 32)
-        sync = lay.vmem_resident_bytes_per_stream(prefetch=False)
-        pre = lay.vmem_resident_bytes_per_stream(prefetch=True)
-        assert pre > sync
+        """The X double buffer counts against the budget.  Both kernels hold
+        two X tiles per stream (the sync pipeline double-buffers its block,
+        the prefetch kernel its scratch), so one count covers both: the
+        residency grows by two X tiles per extra row block of ``block_p``,
+        and a budget one X tile short of four streams derives block_s 2."""
+        narrow = easi_ops.bank_layout(2, 4, 32, block_p=8)
+        wide = easi_ops.bank_layout(2, 4, 32, block_p=16)
+        x_tile = 8 * wide.m_pad * 4  # 8 more rows of one f32 X tile
+        grown = (
+            wide.vmem_resident_bytes_per_stream()
+            - narrow.vmem_resident_bytes_per_stream()
+        )
+        assert grown >= 2 * x_tile
+        sync = wide.vmem_resident_bytes_per_stream()
         monkeypatch.setenv(easi_ops._VMEM_BUDGET_ENV, str(4 * sync))
-        bs_sync = easi_ops.default_block_s(8, lay, interpret=True)
-        bs_pre = easi_ops.default_block_s(8, lay, prefetch=True, interpret=True)
-        assert bs_pre < bs_sync == 4
+        assert easi_ops.default_block_s(8, wide, interpret=True) == 4
+        monkeypatch.setenv(easi_ops._VMEM_BUDGET_ENV, str(4 * sync - x_tile))
+        assert easi_ops.default_block_s(8, wide, interpret=True) == 2
 
     def test_compiled_raises_when_one_stream_cannot_fit(self, monkeypatch):
         """No silent VMEM blowups on real hardware: a shape whose single

@@ -109,9 +109,6 @@ class TestCompression:
     def test_compressed_psum_single_axis(self):
         """On a 1-member axis the compressed psum must reproduce the gradient
         up to quantization error, and EF must hold the residual."""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh
-
         mesh = jax.make_mesh((1,), ("pod",))
         g = {"w": jax.random.normal(jax.random.PRNGKey(1), (64,))}
         ef = init_error_feedback(g)
@@ -120,7 +117,7 @@ class TestCompression:
             out, new_ef = compressed_psum({"w": gw}, {"w": efw}, "pod")
             return out["w"], new_ef["w"]
 
-        f_sh = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+        f_sh = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
         out, new_ef = f_sh(g["w"], ef["w"])
         scale = float(jnp.max(jnp.abs(g["w"]))) / 127.0
         assert float(jnp.max(jnp.abs(out - g["w"]))) <= scale * 0.5 + 1e-7
