@@ -843,6 +843,7 @@ def smbgd_probe_bank_pallas(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="smbgd_probe_bank",  # one kernel name for both DMA schedules
         **_compiler_params(prefetch),
     )(X, W, B, H_hat, step, gamma_hat, active, conv)
 
@@ -977,5 +978,6 @@ def smbgd_step_bank_pallas(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="smbgd_step_bank",  # one kernel name for both DMA schedules
         **_compiler_params(prefetch),
     )(X, W, B, H_hat, step, gamma_hat, active, conv)

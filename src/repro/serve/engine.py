@@ -208,6 +208,28 @@ whose true mixing matrix was registered via ``set_mixing`` (the blind
 statistic can dip early; the Amari check vetoes eviction until the separator
 actually separates).
 
+Tracing: ``run_tick`` and ``step`` mark their phases with
+``jax.profiler.TraceAnnotation`` spans (names in ``SPANS``), so a profiler
+trace shows the host's phases on the device operations' clock::
+
+    serve.run_tick            (metadata tick=<n>)
+      serve.backfill          free slots filled from the scheduler
+      serve.pull              one block from every bound source
+      serve.step              also a root when a caller pushes batches
+        serve.stage           checks, staging copy, host→device transfer
+        serve.launch          the jitted bank step's dispatch
+        serve.ready           the tick timer's sync on the conv leaf
+        serve.outputs         the per-session output slices
+        serve.moments         moments readback and controller
+        serve.health          the health sweep
+        serve.policy          the convergence and drift sweep
+      serve.release           drained sources released
+      serve.probe             parked and quarantined probes
+      serve.autoscale         the autoscaler
+
+    A span is entered once per tick, never per session, and costs under a
+    microsecond when no profiler session is active; tracing adds no sync.
+
 Memory-system knobs (PR 6) — all set on the ``SeparatorBank`` the service
 wraps; the engine threads them to every bank it derives (probe banks pin the
 serving bank's resolved geometry with ``autotune=False``):
@@ -269,6 +291,27 @@ from repro.serve.slo import (
 from repro.stream.bank import BankState, SeparatorBank
 
 PyTree = Any
+
+# The phase spans of a tick (module docstring, "Tracing").  Every name has
+# the ``serve.`` prefix, so none collides with a span a client opens around
+# its own calls.
+SPANS = (
+    "serve.run_tick",
+    "serve.backfill",
+    "serve.pull",
+    "serve.step",
+    "serve.stage",
+    "serve.launch",
+    "serve.ready",
+    "serve.outputs",
+    "serve.moments",
+    "serve.health",
+    "serve.policy",
+    "serve.release",
+    "serve.probe",
+    "serve.autoscale",
+)
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -1223,6 +1266,85 @@ class SeparationService:
         """
         if not batches:
             return {}
+        with _span("serve.step"):
+            P = self.bank.opt.batch_size
+            n = self.bank.easi.n_components
+            with _span("serve.stage"):
+                X, active = self._stage_batches(batches)
+                # time-to-ready tick clock: JAX dispatches
+                # asynchronously, so stopping at dispatch measured nothing on a
+                # real accelerator.  The timer blocks on the bank's conv leaf —
+                # a tiny (S,) vector whose readiness implies the whole bank
+                # program retired — every tick (or 1-in-k under
+                # SLOPolicy.sync_every); block_ticks=True keeps its stronger
+                # full-result sync and is timed as-is.
+                timer = self._timer
+                timer.start()
+                X_dev, active_dev = jnp.asarray(X), jnp.asarray(active)
+                hp = (self._current_hp(),) if self._hp_step else ()
+            with _span("serve.launch"):
+                self.state, Y = self._step(self.state, X_dev, active_dev, *hp)
+            # the batch's device copy goes once the step holds it: kept to
+            # the end of the tick, it would add its size to peak memory
+            del X_dev, active_dev
+            with _span("serve.ready"):
+                if self.block_ticks:
+                    jax.block_until_ready((self.state, Y))
+                    dt, timed = timer.stop(already_synced=True)
+                else:
+                    dt, timed = timer.stop(sync_leaf=self.state.conv)
+            self._n_ticks += 1
+            self._total_samples += P * len(batches)
+            for sid in batches:
+                st = self._stats[sid]
+                st.ticks += 1
+                st.samples += P
+            # slice outputs BEFORE any auto-eviction mutates the slot map:
+            # evicted sessions still receive this tick's separated output.
+            # Slot index as a traced operand (bank._dyn), not a Python-int
+            # constant: a baked index compiles a separate eager slice program
+            # per (slot, width) — a per-slot compile storm on the first tick at
+            # every new width
+            with _span("serve.outputs"):
+                out = {
+                    sid: Y[self.bank._dyn(self._slot_of[sid]), :P, :n]
+                    for sid in batches
+                }
+            served = list(batches.keys())
+            if self._moments is not None:
+                # one (S, 2) host read per tick: fold this tick's raw moments
+                # into each served session's kurtosis EMAs and refresh its μ
+                # multiplier (consumed by _current_hp next tick — traced
+                # operand, no retrace)
+                with _span("serve.moments"):
+                    mom = np.asarray(self.state.moments)
+                    for sid in served:
+                        slot = self._slot_of[sid]
+                        self._ctrl_scale[slot] = self._moments.observe(
+                            sid, float(mom[slot, 0]), float(mom[slot, 1])
+                        )
+            if self._defer_slo:
+                # called from run_tick: the tick's latency record is finished
+                # AFTER the probe phase, so probe time is billed to this tick
+                self._pending_tick = (served, timed, P * len(batches))
+            else:
+                self._finish_tick(dt, served, timed, P * len(batches))
+            if self.health_policy is not None:
+                # containment first: offenders are rolled back / quarantined /
+                # diverged and drop out of this tick's convergence sweep (their
+                # conv statistic was never committed anyway)
+                with _span("serve.health"):
+                    served = self._apply_health(served)
+            if self.policy is not None:
+                with _span("serve.policy"):
+                    self._apply_policy(served)
+            return out
+
+    def _stage_batches(
+        self, batches: Dict[Hashable, jnp.ndarray]
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Check every batch and copy it into the staging buffer; returns
+        the buffer and the ``(S,)`` active mask."""
         unknown = set(batches) - set(self._slot_of)
         if unknown:
             # never silently drop data: queued/parked sessions hold no slot
@@ -1248,7 +1370,6 @@ class SeparationService:
         S = self.bank.n_streams
         P = self.bank.opt.batch_size
         m = self.bank.easi.n_features
-        n = self.bank.easi.n_components
         # reused staging buffer (block-aligned on fused banks): stale data in
         # slots not written this tick only feeds masked-out streams, and the
         # padding region is never written, so it stays zero from __init__
@@ -1264,66 +1385,7 @@ class SeparationService:
             slot = self._slot_of[sid]
             X[slot, :P, :m] = xb
             active[slot] = True
-        # time-to-ready tick clock (PR-8 fix): JAX dispatches asynchronously,
-        # so stopping at dispatch measured nothing on a real accelerator.
-        # The timer blocks on the bank's conv leaf — a tiny (S,) vector whose
-        # readiness implies the whole bank program retired — every tick (or
-        # 1-in-k under SLOPolicy.sync_every); block_ticks=True keeps its
-        # stronger full-result sync and is timed as-is.
-        timer = self._timer
-        timer.start()
-        if self._hp_step:
-            self.state, Y = self._step(
-                self.state, jnp.asarray(X), jnp.asarray(active), self._current_hp()
-            )
-        else:
-            self.state, Y = self._step(self.state, jnp.asarray(X), jnp.asarray(active))
-        if self.block_ticks:
-            jax.block_until_ready((self.state, Y))
-            dt, timed = timer.stop(already_synced=True)
-        else:
-            dt, timed = timer.stop(sync_leaf=self.state.conv)
-        self._n_ticks += 1
-        self._total_samples += P * len(batches)
-        for sid in batches:
-            st = self._stats[sid]
-            st.ticks += 1
-            st.samples += P
-        # slice outputs BEFORE any auto-eviction mutates the slot map: evicted
-        # sessions still receive this tick's separated output.  Slot index as
-        # a traced operand (bank._dyn), not a Python-int constant: a baked
-        # index compiles a separate eager slice program per (slot, width) —
-        # a per-slot compile storm on the first tick at every new width
-        out = {
-            sid: Y[self.bank._dyn(self._slot_of[sid]), :P, :n]
-            for sid in batches
-        }
-        served = list(batches.keys())
-        if self._moments is not None:
-            # one (S, 2) host read per tick: fold this tick's raw moments
-            # into each served session's kurtosis EMAs and refresh its μ
-            # multiplier (consumed by _current_hp next tick — traced operand,
-            # no retrace)
-            mom = np.asarray(self.state.moments)
-            for sid in served:
-                slot = self._slot_of[sid]
-                self._ctrl_scale[slot] = self._moments.observe(
-                    sid, float(mom[slot, 0]), float(mom[slot, 1])
-                )
-        if self._defer_slo:
-            # called from run_tick: the tick's latency record is finished
-            # AFTER the probe phase, so probe time is billed to this tick
-            self._pending_tick = (served, timed, P * len(batches))
-        else:
-            self._finish_tick(dt, served, timed, P * len(batches))
-        if self.health_policy is not None:
-            # containment first: offenders are rolled back / quarantined /
-            # diverged and drop out of this tick's convergence sweep (their
-            # conv statistic was never committed anyway)
-            served = self._apply_health(served)
-        if self.policy is not None:
-            self._apply_policy(served)
-        return out
+        return X, active
 
     def _finish_tick(
         self, dt: float, served: List[Hashable], timed: bool, samples: int
@@ -2369,8 +2431,57 @@ class SeparationService:
         counts in ``metrics['n_empty_ticks']`` and its duration still lands
         in the latency sketch and the deadline check (``n_ticks`` remains
         data ticks only — lifecycle stamps keep their meaning)."""
-        t0 = time.perf_counter()
-        self._backfill()  # deadline/quota gates may have reopened
+        with _span("serve.run_tick", tick=self._n_ticks):
+            t0 = time.perf_counter()
+            with _span("serve.backfill"):
+                self._backfill()  # deadline/quota gates may have reopened
+            with _span("serve.pull"):
+                batches, drained = self._pull_blocks()
+            if batches:
+                self._defer_slo = True
+                try:
+                    out = self.step(batches)
+                finally:
+                    self._defer_slo = False
+            else:
+                out = {}
+            with _span("serve.release"):
+                for sid in drained:
+                    if sid in self._slot_of:
+                        self._release(sid, reason="exhausted")
+            had_oob = bool(self._parked or self._quarantined)
+            with _span("serve.probe"):
+                pt0 = time.perf_counter()
+                self._probe_parked()
+                self._probe_quarantined()
+                pt1 = time.perf_counter()
+            if had_oob:
+                self._last_probe_s = pt1 - pt0  # out-of-band probe phase, timed
+            # autoscale AFTER serve+probe (decisions see this tick's telemetry)
+            # and BEFORE the latency record closes: resize cost is billed to
+            # the tick that resized, so the SLO sketch and the bench's
+            # resize-tick overhead metric both see it
+            with _span("serve.autoscale"):
+                self._autoscale_tick()
+            dt = time.perf_counter() - t0
+            if self._pending_tick is not None:
+                served, timed, samples = self._pending_tick
+                self._pending_tick = None
+                self._finish_tick(dt, served, timed, samples)
+            else:
+                # empty tick: every source degraded/drained, or probe-only work
+                # — distinctly counted, and its wall-clock still faces the
+                # budget (probes end host-synced, so dt is honest without a
+                # sync leaf)
+                self._n_empty_ticks += 1
+                self._record_latency(dt, [])
+            return out
+
+    def _pull_blocks(self) -> Tuple[Dict[Hashable, np.ndarray], List[Hashable]]:
+        """One ``(m, P)`` block from every active session's bound source,
+        returned as its ``(P, m)`` batch; also the sessions whose source
+        drained.  A source that raises anything else degrades its own
+        session's tick only (see ``run_tick``)."""
         P = self.bank.opt.batch_size
         m = self.bank.easi.n_features
         batches: Dict[Hashable, np.ndarray] = {}
@@ -2395,41 +2506,7 @@ class SeparationService:
             if hasattr(src, "pop_retries"):
                 self._n_source_retries += int(src.pop_retries())
             batches[sid] = blk.T
-        if batches:
-            self._defer_slo = True
-            try:
-                out = self.step(batches)
-            finally:
-                self._defer_slo = False
-        else:
-            out = {}
-        for sid in drained:
-            if sid in self._slot_of:
-                self._release(sid, reason="exhausted")
-        had_oob = bool(self._parked or self._quarantined)
-        pt0 = time.perf_counter()
-        self._probe_parked()
-        self._probe_quarantined()
-        pt1 = time.perf_counter()
-        if had_oob:
-            self._last_probe_s = pt1 - pt0  # out-of-band probe phase, timed
-        # autoscale AFTER serve+probe (decisions see this tick's telemetry)
-        # and BEFORE the latency record closes: resize cost is billed to the
-        # tick that resized, so the SLO sketch and the bench's resize-tick
-        # overhead metric both see it
-        self._autoscale_tick()
-        dt = time.perf_counter() - t0
-        if self._pending_tick is not None:
-            served, timed, samples = self._pending_tick
-            self._pending_tick = None
-            self._finish_tick(dt, served, timed, samples)
-        else:
-            # empty tick: every source degraded/drained, or probe-only work —
-            # distinctly counted, and its wall-clock still faces the budget
-            # (probes end host-synced, so dt is honest without a sync leaf)
-            self._n_empty_ticks += 1
-            self._record_latency(dt, [])
-        return out
+        return batches, drained
 
     @property
     def last_faults(self) -> Dict[Hashable, str]:

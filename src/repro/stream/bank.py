@@ -872,15 +872,21 @@ class SeparatorBank:
         outputs, so a steady-state tick allocates nothing (the serving hot
         loop).  ``with_hyperparams=True`` builds the 4-argument flavour
         ``step(state, X, active, hyperparams)`` — per-stream (μ, β, γ) as
-        traced operands, the drift-watchdog's no-retrace μ-boost hook."""
+        traced operands, the drift-watchdog's no-retrace μ-boost hook.
+        Either flavour is named ``bank_step``, the name its dispatch carries
+        in a profiler trace (``PjitFunction(bank_step)``)."""
         if with_hyperparams:
-            fn = lambda st, X, active, hp: self.step(
-                st, X, active=active, hyperparams=hp
-            )
+
+            def bank_step(st, X, active, hp):
+                return self.step(st, X, active=active, hyperparams=hp)
+
         else:
-            fn = lambda st, X, active: self.step(st, X, active=active)
+
+            def bank_step(st, X, active):
+                return self.step(st, X, active=active)
+
         donate = self._donate_default(donate)
-        return jax.jit(fn, donate_argnums=(0,) if donate else ())
+        return jax.jit(bank_step, donate_argnums=(0,) if donate else ())
 
     def make_epoch(self, donate: Optional[bool] = None):
         """Jitted ``epoch(state, X) -> (state, Y)`` with donated state

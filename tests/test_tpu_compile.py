@@ -129,3 +129,46 @@ def test_sharded_step_compiles_without_collectives(topo, compiled_lowering):
     hlo = step.lower(state, X).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert not [c for c in COLLECTIVES if c in hlo]
+
+
+@pytest.mark.parametrize("prefetch", [False, True], ids=["sync", "prefetch"])
+@pytest.mark.parametrize("kind", ["step", "probe"])
+def test_megakernel_carries_one_name_for_both_schedules(topo, kind, prefetch):
+    """The kernel's name is fixed, not taken from the kernel function that
+    the DMA schedule picks: a trace finds it by that name either way.
+    Lowered, not compiled: the HLO keeps each instruction's ``op_name``."""
+    lay = easi_ops.bank_layout(
+        EASI.n_components, EASI.n_features, SMBGD.batch_size, interpret=False
+    )
+    fn = easi_ops.smbgd_step_bank if kind == "step" else easi_ops.smbgd_probe_bank
+    call = lambda *a: fn(
+        *a, block_p=lay.block_p, interpret=False, prefetch=prefetch, moments=True
+    )
+    args = _kernel_args(SingleDeviceSharding(topo.devices[0]), 64, lay)
+    hlo = jax.jit(call).lower(*args).as_text(dialect="hlo", debug_info=True)
+    assert "tpu_custom_call" in hlo
+    assert f'op_name="smbgd_{kind}_bank/pallas_call"' in hlo
+
+
+def test_bank_step_dispatches_under_its_name(topo, compiled_lowering):
+    """The serving step's jitted function is ``bank_step``, the name its
+    dispatch carries in a profiler trace."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    S = 64
+    bank = SeparatorBank(EASI, SMBGD, S, fused=True, moments=True)
+    lay = bank.layout
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    state = BankState(
+        B=sds((S, lay.n_pad, lay.m_pad), jnp.float32),
+        H_hat=sds((S, lay.n_pad, lay.n_pad), jnp.float32),
+        step=sds((S,), jnp.int32),
+        conv=sds((S,), jnp.float32),
+        health=sds((S,), jnp.int32),
+        moments=sds((S, 2), jnp.float32),
+    )
+    X = sds((S, lay.P_pad, lay.m_pad), jnp.float32)
+    active = sds((S,), jnp.bool_)
+    step = bank.make_step(donate=False)
+    hlo = step.lower(state, X, active).as_text(dialect="hlo", debug_info=True)
+    assert hlo.startswith("HloModule jit_bank_step")
+    assert 'op_name="smbgd_step_bank/pallas_call"' in hlo
