@@ -12,12 +12,15 @@ each tick is closed, the client takes every returned ``(P, n)`` output to
 the host before the next tick starts; a session whose lifetime is over
 drains and the service releases it.  After the window the device's peak
 memory is read, the program's state is freed, and the reference replays
-every session's blocks, at the stated precision and at the step below.
+every session's blocks, at the stated precision and at the step below,
+in lockstep, comparing each step's outputs as they come.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import json
+import resource
 import shutil
 import statistics
 import sys
@@ -29,7 +32,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from benchlib import compare, registry, reference, trace as trace_lib
+from benchlib import compare, registry, reference, spans, trace as trace_lib
 from benchlib.traffic import Population, RingSource, make_traffic
 
 
@@ -51,10 +54,11 @@ class CompileCounter:
             self.n += 1
 
 
-def _build_service(config: Dict, slots: int, seed: int, queue: int):
+def build_bank(config: Dict, slots: int):
+    """The served bank: the fused whole-step megakernel with moment
+    telemetry and the configuration's blow-up bound."""
     from repro.core.easi import EASIConfig
     from repro.core.smbgd import SMBGDConfig
-    from repro.serve import HealthPolicy, SeparationService
     from repro.stream import SeparatorBank
 
     easi = EASIConfig(
@@ -65,12 +69,17 @@ def _build_service(config: Dict, slots: int, seed: int, queue: int):
         batch_size=int(config["P"]), mu=float(config["mu"]),
         beta=float(config["beta"]), gamma=float(config["gamma"]),
     )
-    bank = SeparatorBank(
+    return SeparatorBank(
         easi, opt, slots, fused=True, moments=True,
         blowup=float(config["health_blowup_bound"]),
     )
+
+
+def _build_service(config: Dict, slots: int, seed: int, queue: int):
+    from repro.serve import HealthPolicy, SeparationService
+
     return SeparationService(
-        bank, seed=seed % (2**31 - 1), health_policy=HealthPolicy(),
+        build_bank(config, slots), seed=seed % (2**31 - 1), health_policy=HealthPolicy(),
         max_queue=queue,
     )
 
@@ -131,19 +140,34 @@ def _device(chips: int, require_chip: bool):
     return devs[:chips]
 
 
+def peak_rss_bytes() -> int:
+    """The process's peak resident memory on the host so far."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
 def _json_number(x: float):
     """A float as JSON allows it: a non-finite reading becomes its name."""
     return x if np.isfinite(x) else repr(float(x))
 
 
 class TracedRun:
-    """What a per-layer reader gets: the reduced trace and the cell."""
+    """What a per-layer reader gets: the loaded and the reduced trace, and
+    the cell."""
 
-    def __init__(self, reduced, config, device_kind, sessions_per_tick):
+    def __init__(self, reduced, config, device_kind, sessions_per_tick, trace=None):
         self.reduced = reduced
         self.config = config
         self.device_kind = device_kind
         self.sessions_per_tick = sessions_per_tick
+        self.trace = trace
+
+    @functools.cached_property
+    def phase_metrics(self) -> Optional[Dict[str, float]]:
+        """The tick loop's phase metrics from the program's ``serve.*``
+        spans (``spans.metrics``); None without a trace or without them."""
+        if self.trace is None:
+            return None
+        return spans.metrics(spans.reduce(self.trace))
 
     def op_ms_per_tick(self, pattern: str) -> Optional[float]:
         """Device milliseconds per tick of the operations matching
@@ -293,14 +317,17 @@ def run(
     del svc, end, slots, outputs, final
     gc.collect()
 
-    stated = config["matmul_precision"]
-    ref = reference.replay(config, traffic, np.arange(N), pulls, precision=stated)
-    ctl = reference.replay(config, traffic, np.arange(N), pulls,
-                           precision=reference.BELOW[stated])
-    numbers = compare.compare(served, ref, ctl, **compare.options(limits, config))
+    t_check = time.perf_counter()
+    opts = compare.options(limits, config)
+    ref, ctl = reference.replay_beside(config, traffic, np.arange(N), pulls, Y, delivered,
+                                       head=opts["share_steps"])
+    numbers = compare.compare(served, ref, ctl, **opts)
     numbers["compiles_in_window"] = float(compiles_in_window)
     held = dict(limits["limits"], compiles_in_window=0)
     correct = compare.verdict(numbers, held)
+    diag = compare.diagnostics(served, ref)
+    diag["check_s"] = time.perf_counter() - t_check
+    diag["peak_rss_bytes"] = peak_rss_bytes()
 
     dev = devs[0]
     device = {
@@ -310,12 +337,13 @@ def run(
     metrics: Dict[str, Dict] = {}
     result: Dict = {}
     if traced:
-        red = trace_lib.reduce(trace_lib.load(trace_lib.find_xplane(trace_dir)))
+        loaded = trace_lib.load(trace_lib.find_xplane(trace_dir))
+        red = trace_lib.reduce(loaded)
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"] = red.busy_s
         device["window_s"] = red.window_s
         sessions_per_tick = served_timed / max(timed, 1)
-        tr = TracedRun(red, config, dev.device_kind, sessions_per_tick)
+        tr = TracedRun(red, config, dev.device_kind, sessions_per_tick, trace=loaded)
         for spec in registry.metrics_of(root, workload_name, "per_layer"):
             value = registry.reader(root, spec["name"])(tr)
             if value is not None:
@@ -345,7 +373,6 @@ def run(
         f"{int(numbers['compared_worst'])} under max_update",
         file=err,
     )
-    diag = compare.diagnostics(served, ref)
     print("readings: " + " ".join(f"{k}={v:.4g}" for k, v in diag.items()), file=err)
     checks = {
         k: {"value": _json_number(numbers[k]), "limit": held[k]}

@@ -18,10 +18,11 @@ there any two computations, the reference on two backends too, part ways
 far more than in the median session.  So the worst session's error swings
 from seed to seed by a factor of 50, as far as the control's does, and no
 limit holds on it.  The median session's error is steady from seed to
-seed and tells the two apart by a factor of 15 or more.  The worst session
-is read as a share of the control's error: the control (the reference one
-matmul precision step lower, replayed over the same blocks) parts from the
-reference in the same sessions, and the share is steady; it is read only
+seed, but it grows tenfold and more between tens and thousands of steps,
+the control's with it.  So both are read as shares of the control's: the
+control (the reference one matmul precision step lower, replayed over the
+same blocks) parts from the reference in the same sessions and over the
+same steps, and the shares are steady.  The worst session's share is read only
 over the sessions whose reference update ``‖Ĥ′B‖_F / ‖B‖_F`` never passed
 ``max_update`` (``bench/limits``), and catches a fault in one session that
 the median cannot see.
@@ -36,21 +37,37 @@ number the file gives no limit is read and printed, not held):
   not finite, and sessions that blew up in the reference (a non-finite
   step, or an update past ``blowup_margin`` times the bound) that the
   service never flagged: the health guarantee broken (limit 0);
-* ``y_med``, ``h_med``, ``b_med``: the median compared session's error;
+* ``y_ctl_med``, ``h_ctl_med``, ``b_ctl_med``: the median compared
+  session's error over the control's median compared session's error,
+  over the same sessions and outputs (for ``h`` and ``b``, the sessions
+  whose state is known).  Both grow with the steps a session takes, and
+  their ratio holds still, so the limit holds at any step count; the
+  control reads 1;
 * ``y_ctl_share``, ``h_ctl_share``, ``b_ctl_share``: the largest, over the
   compared sessions whose update stayed at or under ``max_update``, of the
   session's error over the control's error in that session (floored at the
-  control's median session); the control reads 1.
+  control's median session); the control reads 1.  Over thousands of steps
+  the served path and the control each turn a few sessions' trajectories
+  apart from the reference's, in different sessions, and these shares pass
+  1 in sound runs; so ``y_ctl_share`` is read over each session's first
+  ``share_steps`` outputs where the limits file gives that count, and the
+  state's shares are printed, not held;
+* ``y_med``, ``h_med``, ``b_med``: the median compared session's error
+  itself, printed beside the shares and not held: it grows with the steps.
+
+The outputs are reduced a few steps at a time (``CHUNK``), so a run of
+thousands of steps per session needs no float64 copy of them.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
-ORDER = ("missing", "health_missed", "y_med", "h_med", "b_med",
-         "y_ctl_share", "h_ctl_share", "b_ctl_share")
+ORDER = ("missing", "health_missed", "y_ctl_med", "h_ctl_med", "b_ctl_med",
+         "y_ctl_share", "h_ctl_share", "b_ctl_share", "y_med", "h_med", "b_med")
 NON_FINITE = 1 | 2 | 4  # the health word's bits for B′, Ĥ′, Y
+CHUNK = 1 << 22  # output entries reduced at a time
 
 
 def _per_session(got: np.ndarray, ref: np.ndarray, mask=None) -> np.ndarray:
@@ -69,17 +86,60 @@ def _per_session(got: np.ndarray, ref: np.ndarray, mask=None) -> np.ndarray:
     return diff.max(axis=axes) / np.maximum(mag.max(axis=axes), 1e-30)
 
 
+class OutputGaps:
+    """Each session's largest output gap and largest reference magnitude,
+    kept as blocks of output rows come: ``errors()`` is ``_per_session``
+    over every row added.  In float32: a gap of two float32 numbers rounds
+    by at most 2^-24 of itself."""
+
+    def __init__(self, sessions: int):
+        self.gap = np.zeros((sessions,))
+        self.mag = np.zeros((sessions,))
+
+    def add(self, got: np.ndarray, ref: np.ndarray, keep: np.ndarray) -> None:
+        """Rows ``(c, N, P, n)`` of the outputs and of the reference, and
+        which of them count, ``keep (c, N)``."""
+        d = np.abs(got.astype(np.float32) - ref.astype(np.float32))
+        d[~np.isfinite(d)] = np.inf
+        a = np.abs(ref.astype(np.float32))
+        d[~keep] = 0.0
+        a[~keep] = 0.0
+        self.gap = np.maximum(self.gap, d.max(axis=(0, 2, 3)))
+        self.mag = np.maximum(self.mag, a.max(axis=(0, 2, 3)))
+
+    def errors(self) -> np.ndarray:
+        return self.gap / np.maximum(self.mag, 1e-30)
+
+
+def _steps_per_chunk(Y: np.ndarray, sessions: int) -> int:
+    return max(1, CHUNK // max(1, sessions * int(np.prod(Y.shape[2:]))))
+
+
+def _per_session_steps(got: np.ndarray, ref: np.ndarray, delivered: np.ndarray,
+                       sessions: np.ndarray) -> np.ndarray:
+    """``_per_session`` of the outputs ``(K, N, P, n)`` for each of
+    ``sessions`` (indices into axis 1), over the rows ``delivered (K, N)``
+    keeps, reduced a few steps at a time (``OutputGaps``): no whole copy
+    of the outputs is made."""
+    sessions = np.asarray(sessions, dtype=np.int64)
+    every = sessions.size == got.shape[1] and (sessions == np.arange(got.shape[1])).all()
+    gaps = OutputGaps(sessions.size)
+    step = _steps_per_chunk(got, sessions.size)
+    for k0 in range(0, got.shape[0], step):
+        rows = slice(k0, k0 + step)
+        g, r, keep = got[rows], ref[rows], delivered[rows]
+        if not every:
+            g, r, keep = g[:, sessions], r[:, sessions], keep[:, sessions]
+        gaps.add(g, r, keep)
+    return gaps.errors()
+
+
 def _worst(e: np.ndarray) -> float:
     return float(e.max()) if e.size else 0.0
 
 
 def _median(e: np.ndarray) -> float:
     return float(np.median(e)) if e.size else 0.0
-
-
-def _y_mask(served: Dict) -> np.ndarray:
-    """(N, K, 1, 1): which of each session's output rows were delivered."""
-    return served["delivered"].T[:, :, None, None]
 
 
 def compared_sessions(ref: Dict, max_update: float = float("inf")) -> np.ndarray:
@@ -91,9 +151,14 @@ def compared_sessions(ref: Dict, max_update: float = float("inf")) -> np.ndarray
 def health_missed(served: Dict, ref: Dict, bound: float, margin: float) -> int:
     """Sessions with non-finite delivered outputs or final state, and clear
     blow-ups of the reference that the service never flagged."""
-    Y = np.where(served["delivered"][:, :, None, None], served["Y"], 0.0)
+    Y, delivered = served["Y"], served["delivered"]
     known = served["known"]
-    bad = ~np.isfinite(Y).all(axis=(0, 2, 3))
+    bad = np.zeros((Y.shape[1],), bool)
+    step = _steps_per_chunk(Y, Y.shape[1])
+    for k0 in range(0, Y.shape[0], step):
+        rows = slice(k0, k0 + step)
+        ok = np.isfinite(Y[rows]) | ~delivered[rows][:, :, None, None]
+        bad |= ~ok.all(axis=(0, 2, 3))
     bad |= known & ~np.isfinite(served["B"]).all(axis=(1, 2))
     bad |= known & ~np.isfinite(served["H"]).all(axis=(1, 2))
     blew = ((ref["word"] & NON_FINITE) != 0) | (ref["delta_max"] > margin * bound)
@@ -102,19 +167,29 @@ def health_missed(served: Dict, ref: Dict, bound: float, margin: float) -> int:
     return int((bad | (blew & ~flagged)).sum())
 
 
-def _errors(got: Dict, ref: Dict, clean: np.ndarray, mask: np.ndarray) -> Dict:
+def _errors(got: Dict, ref: Dict, clean: np.ndarray, delivered: np.ndarray,
+            head: Optional[int], given: Dict) -> Dict:
     """Per-session ``y``, ``h``, ``b`` errors of ``got`` against ``ref``
-    over the sessions ``clean``, ``y`` over the output rows ``mask`` keeps."""
+    over the sessions ``clean``: ``y`` over the output rows ``delivered``
+    keeps, ``y_head`` over each session's first ``head`` of them (all,
+    where None).  Where ``given`` holds ``y_err`` (``reference.replay_beside``
+    took every session's output errors as it went), those are the ``y``s."""
+    if "y_err" in given:
+        y, y_head = given["y_err"][clean], given["y_err_head"][clean]
+    else:
+        y = _per_session_steps(got["Y"], ref["Y"], delivered, clean)
+        y_head = y if head is None or head >= len(got["Y"]) else _per_session_steps(
+            got["Y"][:head], ref["Y"][:head], delivered[:head], clean)
     return {
-        "y": _per_session(got["Y"][:, clean].transpose(1, 0, 2, 3),
-                          ref["Y"][:, clean].transpose(1, 0, 2, 3), mask[clean]),
+        "y": y, "y_head": y_head,
         "h": _per_session(got["H"][clean], ref["H"][clean]),
         "b": _per_session(got["B"][clean], ref["B"][clean]),
     }
 
 
 def compare(served: Dict, ref: Dict, ctl: Dict, max_update: float = float("inf"),
-            bound: float = float("inf"), blowup_margin: float = 10.0) -> Dict[str, float]:
+            bound: float = float("inf"), blowup_margin: float = 10.0,
+            share_steps: Optional[int] = None) -> Dict[str, float]:
     """``served`` holds, for N sessions, ``Y (K, N, P, n)`` (session ``i``'s
     ``k``-th output in row ``k``), ``delivered (K, N)``, ``pulls (N,)``,
     ``B``/``H`` after each session's last step where ``known (N,)`` and
@@ -122,7 +197,10 @@ def compare(served: Dict, ref: Dict, ctl: Dict, max_update: float = float("inf")
     ``reference.replay`` over the same sessions and as many steps as each
     pulled, at the stated precision and at the step below it.  A session
     that pulled nothing is not compared.  A session whose state cannot be
-    read back counts as missing."""
+    read back counts as missing.  Where ``ref`` and ``ctl`` are
+    ``reference.replay_beside``'s, with no ``Y`` and every session's
+    output error taken as the replay went (``y_err``: the served path's
+    in ``ref``, the control's in ``ctl``), the outputs' errors are those."""
     pulled = served["pulls"] > 0
     clean = compared_sessions(ref)
     clean = clean[pulled[clean]]
@@ -131,9 +209,8 @@ def compare(served: Dict, ref: Dict, ctl: Dict, max_update: float = float("inf")
     known = served["known"][clean]
     flag_ref = set(np.flatnonzero(ref["flagged"]).tolist())
     lost = (served["pulls"][clean] - outputs[clean]).sum() + (~known).sum()
-    mask = _y_mask(served)
-    e = _errors(served, ref, clean, mask)
-    e_ctl = _errors(ctl, ref, clean, mask)
+    e = _errors(served, ref, clean, served["delivered"], share_steps, ref)
+    e_ctl = _errors(ctl, ref, clean, served["delivered"], share_steps, ctl)
     out = {
         "missing": float(lost),
         "health_missed": float(health_missed(served, ref, bound, blowup_margin)),
@@ -147,7 +224,9 @@ def compare(served: Dict, ref: Dict, ctl: Dict, max_update: float = float("inf")
     for k in ("y", "h", "b"):
         sel = np.ones_like(known) if k == "y" else known
         out[f"{k}_med"] = _median(e[k][sel])
-        share = e[k] / np.maximum(e_ctl[k], max(_median(e_ctl[k]), 1e-30))
+        out[f"{k}_ctl_med"] = out[f"{k}_med"] / max(_median(e_ctl[k][sel]), 1e-30)
+        w = "y_head" if k == "y" else k
+        share = e[w] / np.maximum(e_ctl[w], max(_median(e_ctl[w]), 1e-30))
         out[f"{k}_ctl_share"] = _worst(share[calm & sel])
     return out
 
@@ -157,8 +236,10 @@ def diagnostics(served: Dict, ref: Dict) -> Dict[str, float]:
     of the per-session errors over the sessions the reference never
     flagged, and the worst of them under other ``max_update`` rules."""
     ok = ~ref["flagged"] & served["known"] & (served["pulls"] > 0)
-    ey = _per_session(served["Y"].transpose(1, 0, 2, 3),
-                      ref["Y"].transpose(1, 0, 2, 3), _y_mask(served))
+    ey = ref.get("y_err")
+    if ey is None:
+        ey = _per_session_steps(served["Y"], ref["Y"], served["delivered"],
+                                np.arange(served["Y"].shape[1]))
     eb = _per_session(served["B"], ref["B"])
     eh = _per_session(served["H"], ref["H"])
     out = {}
@@ -182,6 +263,7 @@ def options(limits_file: Dict, config: Dict) -> Dict[str, float]:
     out = {k: float(limits_file[k]) for k in ("max_update", "blowup_margin")
            if k in limits_file}
     out["bound"] = float(config["health_blowup_bound"])
+    out["share_steps"] = limits_file.get("share_steps")
     return out
 
 

@@ -110,39 +110,110 @@ def make_step(config: Dict, precision: str = "highest"):
     return jax.jit(jax.vmap(one))
 
 
+class _Run:
+    """One precision's replay of every session, a step at a time, on
+    ``device`` (the default device when None)."""
+
+    def __init__(self, config: Dict, traffic, sessions, steps, precision: str,
+                 device=None):
+        self.sessions = np.asarray(sessions, dtype=np.int64)
+        self.steps = np.asarray(steps, dtype=np.int64)
+        N, n = len(self.sessions), int(config["n"])
+        self.traffic = traffic
+        self.step_fn = make_step(config, precision)
+        self.put = functools.partial(jax.device_put, device=device)
+        B0 = traffic.B0[self.sessions % traffic.streams]
+        self.B, self.H = self.put(B0), self.put(np.zeros((N, n, n), np.float32))
+        self.st = self.put(np.zeros((N,), np.int32))
+        self.B_end, self.H_end = B0.copy(), np.zeros((N, n, n), np.float32)
+        self.words = np.zeros((N,), np.int32)
+        self.delta_max = np.zeros((N,))
+        self.k = 0
+
+    def step(self) -> np.ndarray:
+        """Every session's next block; returns the outputs ``(N, P, n)``."""
+        k, steps = self.k, self.steps
+        self.B, self.H, self.st, Y, word, delta = self.step_fn(
+            self.B, self.H, self.st, self.put(self.traffic.batch(self.sessions, k)))
+        Y, word, delta = jax.device_get((Y, word, delta))
+        live = steps > k
+        self.words |= np.where(live, word, 0)
+        self.delta_max = np.where(live, np.fmax(self.delta_max, delta), self.delta_max)
+        done = steps == k + 1
+        if done.any():
+            self.B_end[done] = np.asarray(self.B)[done]
+            self.H_end[done] = np.asarray(self.H)[done]
+        self.k = k + 1
+        return Y
+
+    def state(self) -> Dict[str, np.ndarray]:
+        """Each session's state, flags and largest update after
+        ``min(steps taken, steps[i])`` steps."""
+        on = (self.steps > self.k)[:, None, None]
+        return {
+            "B": np.where(on, np.asarray(self.B), self.B_end),
+            "H": np.where(on, np.asarray(self.H), self.H_end),
+            "flagged": self.words != 0, "word": self.words.copy(),
+            "delta_max": self.delta_max.copy(),
+        }
+
+
 def replay(config: Dict, traffic, sessions, steps, precision: str = "highest",
-           device=None) -> Dict[str, np.ndarray]:
+           device=None, marks=()) -> Dict[str, np.ndarray]:
     """Run each of ``sessions`` (ids into ``traffic``) from its ``B0`` over
     its first ``steps[i]`` blocks, on ``device`` (the default device when
     None).  Returns the outputs ``Y (K, N, P, n)`` (``K = max(steps)``;
     rows past a session's own steps are not its), the state each session
     reached after its own steps (``B``, ``H``), which sessions were ever
     flagged, with the OR of their health words (``word``), and each
-    session's largest relative update ``delta_max``."""
-    sessions = np.asarray(sessions, dtype=np.int64)
-    steps = np.asarray(steps, dtype=np.int64)
-    N, K = len(sessions), int(steps.max()) if len(steps) else 0
-    n, m, P = int(config["n"]), int(config["m"]), int(config["P"])
-    step_fn = make_step(config, precision)
-    put = functools.partial(jax.device_put, device=device)
-    B0 = traffic.B0[sessions % traffic.streams]
-    B, H = put(B0), put(np.zeros((N, n, n), np.float32))
-    st = put(np.zeros((N,), np.int32))
-    B_end, H_end = B0.copy(), np.zeros((N, n, n), np.float32)
-    Ys = np.zeros((K, N, P, n), np.float32)
-    words = np.zeros((N,), np.int32)
-    delta_max = np.zeros((N,))
+    session's largest relative update ``delta_max``.  For each step count
+    in ``marks`` the result's ``at[mark]`` holds the same state, flags and
+    updates as they stood after ``min(mark, steps[i])`` steps."""
+    run = _Run(config, traffic, sessions, steps, precision, device)
+    K = int(run.steps.max()) if len(run.steps) else 0
+    Ys = np.zeros((K, len(run.sessions), int(config["P"]), int(config["n"])), np.float32)
+    at = {}
     for k in range(K):
-        B, H, st, Y, word, delta = step_fn(B, H, st, put(traffic.batch(sessions, k)))
-        live = steps > k
-        Ys[k] = np.asarray(Y)
-        words |= np.where(live, np.asarray(word), 0)
-        delta_max = np.where(live, np.fmax(delta_max, np.asarray(delta)), delta_max)
-        done = steps == k + 1
-        if done.any():
-            B_end[done] = np.asarray(B)[done]
-            H_end[done] = np.asarray(H)[done]
-    return {
-        "Y": Ys, "B": B_end, "H": H_end,
-        "flagged": words != 0, "word": words, "delta_max": delta_max,
-    }
+        Ys[k] = run.step()
+        if k + 1 in marks:
+            at[k + 1] = run.state()
+    return {"Y": Ys, **run.state(), "at": at}
+
+
+def replay_beside(config: Dict, traffic, sessions, steps, served_Y, delivered,
+                  device=None, marks=(), head=None):
+    """The reference at the configuration's precision and the control one
+    step below it, replayed in lockstep as ``replay`` does, each step's
+    outputs compared as they come (``compare.OutputGaps``) with the served
+    ones ``served_Y (K, N, P, n)`` and the control's with the reference's,
+    over the rows ``delivered (K, N)`` keeps: neither replay's outputs are
+    kept.  Returns ``(ref, ctl)``, each as ``replay``'s result without
+    ``Y`` and with ``y_err``: per session, the served outputs' error
+    against the reference (in ``ref``) and the control's (in ``ctl``), and
+    ``y_err_head``, the same over each session's first ``head`` outputs
+    (all, where None); ``at[mark]`` holds them too."""
+    from benchlib.compare import OutputGaps
+
+    stated = config["matmul_precision"]
+    runs = [_Run(config, traffic, sessions, steps, p, device)
+            for p in (stated, BELOW[stated])]
+    N = len(runs[0].sessions)
+    gaps = [OutputGaps(N), OutputGaps(N)]
+    heads = [None, None]
+    at = ({}, {})
+
+    def errors(i):
+        e = gaps[i].errors()
+        return {"y_err": e, "y_err_head": e if heads[i] is None else heads[i]}
+
+    for k in range(int(runs[0].steps.max()) if N else 0):
+        y_ref, y_ctl = runs[0].step(), runs[1].step()
+        keep = delivered[k][None]
+        gaps[0].add(served_Y[k][None], y_ref[None], keep)
+        gaps[1].add(y_ctl[None], y_ref[None], keep)
+        if k + 1 == head:
+            heads = [g.errors() for g in gaps]
+        if k + 1 in marks:
+            for i, run in enumerate(runs):
+                at[i][k + 1] = {**run.state(), **errors(i)}
+    return tuple({**run.state(), **errors(i), "at": at[i]} for i, run in enumerate(runs))

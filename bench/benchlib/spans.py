@@ -192,10 +192,9 @@ def _named_gaps(busy0, lo, hi, spans, harness, k) -> List[Tuple[str, float]]:
     return named
 
 
-# The per-layer metrics the phases would feed, each the self milliseconds
-# per tick of its spans, summed.  ``bench/phases.py`` prints them; the
-# harness's readers get only ``trace.reduce``'s numbers, so none of them is
-# in ``BENCHMARK.json`` yet.
+# The per-layer metrics the phases feed, each the self milliseconds per
+# tick of its spans, summed.  ``bench/phases.py`` prints them all; the
+# readers in ``bench/metrics/`` take theirs from ``TracedRun.phase_metrics``.
 METRICS = {
     "pull_ms_per_tick": ("serve.pull",),
     "stage_ms_per_tick": ("serve.stage", "serve.launch"),

@@ -219,3 +219,22 @@ def test_witness_sets_the_served_path_beside_both_references(capsys):
     assert got["ref_dev-ref_cpu"]["y"] == 0.0
     assert got["kernel-ref_cpu"]["y"] * 3 < got["ctl_cpu-ref_cpu"]["y"]
     assert got["kernel-vmap"]["compared"] > 0
+
+
+def test_thousands_of_steps_of_a_sound_bank_are_correct():
+    """The steady paper cell rehearsed at 8 sessions for 2,048 steps each,
+    the bank driven by its own jitted step as a fast served path would
+    drive it: the comparison calls it correct at every mark, and the
+    control not."""
+    from calibrate import readings
+    from control import control_readings
+
+    marks = (64, 2048)
+    rows, _ = readings(ROOT, CELLS[0], seed=2147483659, marks=marks, sessions=8,
+                       out=io.StringIO())
+    for row in rows:
+        assert row["correct"], row
+        assert row["compared"] > 0
+    for k, numbers, ok in control_readings(ROOT, CELLS[0], 2147483659, marks, sessions=8):
+        assert not ok, numbers
+        assert numbers["y_ctl_med"] == 1.0

@@ -12,11 +12,12 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "bench"))
 
-from benchlib import spans, trace  # noqa: E402
+from benchlib import cell, registry, spans, trace  # noqa: E402
 from benchlib.trace import Event, Trace  # noqa: E402
 from test_bench_trace import RECORDED, _two_ticks  # noqa: E402
 
 E = Event
+PHASE_READERS = ("outputs_ms_per_tick", "dispatches_per_tick")
 
 
 def _tick(t0, meta=""):
@@ -141,6 +142,27 @@ def test_readers_report_nothing_without_program_spans(source):
     trace.reduce(tr)  # the harness's own reduction still reads it
     assert spans.reduce(tr) is None
     assert spans.metrics(None) is None
+    run = cell.TracedRun(trace.reduce(tr), {}, "TPU v5 lite", 2, trace=tr)
+    for name in PHASE_READERS:
+        assert registry.reader(ROOT, name)(run) is None
+
+
+@pytest.mark.parametrize("with_trace", [True, False])
+def test_phase_readers_of_the_benchmark(with_trace):
+    """The phase metrics that ``BENCHMARK.json`` lists, read by their
+    readers from the loaded trace a traced run hands them; nothing where
+    the run holds no trace."""
+    tr = _traced()
+    run = cell.TracedRun(trace.reduce(tr), {}, "TPU v5 lite", 2,
+                         trace=tr if with_trace else None)
+    listed = {m["name"] for m in registry.benchmark(ROOT)["per_layer"]}
+    assert set(PHASE_READERS) <= listed
+    got = {name: registry.reader(ROOT, name)(run) for name in PHASE_READERS}
+    if with_trace:
+        assert got == pytest.approx({"outputs_ms_per_tick": 15e-6,
+                                     "dispatches_per_tick": 5.0})
+    else:
+        assert got == {name: None for name in PHASE_READERS}
 
 
 def test_program_spans_leave_the_harness_reduction_unchanged():
