@@ -219,7 +219,8 @@ trace shows the host's phases on the device operations' clock::
         serve.stage           checks, staging copy, host→device transfer
         serve.launch          the jitted bank step's dispatch
         serve.ready           the tick timer's sync on the conv leaf
-        serve.outputs         the per-session output slices
+        serve.outputs         the output slices, one program (metadata
+                              sessions=<k> width=<S>)
         serve.moments         moments readback and controller
         serve.health          the health sweep
         serve.policy          the convergence and drift sweep
@@ -1262,13 +1263,13 @@ class SeparationService:
 
         On a fused bank the staging buffer is allocated block-aligned
         (``(S, P_pad, m_pad)``) so the jitted step consumes it with no
-        re-padding copy; outputs are sliced back to ``(P, n)`` per session.
+        re-padding copy; one compiled program cuts every session's ``(P, n)``
+        output from the step's result (``SeparatorBank.slot_outputs``).
         """
         if not batches:
             return {}
         with _span("serve.step"):
             P = self.bank.opt.batch_size
-            n = self.bank.easi.n_components
             with _span("serve.stage"):
                 X, active = self._stage_batches(batches)
                 # time-to-ready tick clock: JAX dispatches
@@ -1301,15 +1302,16 @@ class SeparationService:
                 st.samples += P
             # slice outputs BEFORE any auto-eviction mutates the slot map:
             # evicted sessions still receive this tick's separated output.
-            # Slot index as a traced operand (bank._dyn), not a Python-int
-            # constant: a baked index compiles a separate eager slice program
-            # per (slot, width) — a per-slot compile storm on the first tick at
-            # every new width
-            with _span("serve.outputs"):
-                out = {
-                    sid: Y[self.bank._dyn(self._slot_of[sid]), :P, :n]
-                    for sid in batches
-                }
+            # One compiled program per bank width cuts every served slot's
+            # output (slot indices as a traced operand): one dispatch per
+            # 128 sessions, and no compile when the served count changes
+            with _span(
+                "serve.outputs", sessions=len(batches), width=self.bank.n_streams
+            ):
+                ys = self.bank.slot_outputs(
+                    Y, [self._slot_of[sid] for sid in batches]
+                )
+                out = dict(zip(batches, ys))
             served = list(batches.keys())
             if self._moments is not None:
                 # one (S, 2) host read per tick: fold this tick's raw moments
@@ -2176,15 +2178,9 @@ class SeparationService:
                 args = args + (bank._bank_hyperparams(),)
             out_state, _Y = fn(*args)
             jax.block_until_ready(out_state.conv)
-            # per-session output slice of the serving step (dynamic slot
-            # index — one gather program covers every slot at this width)
-            jax.block_until_ready(
-                _Y[
-                    bank._dyn(0),
-                    : bank.opt.batch_size,
-                    : bank.easi.n_components,
-                ]
-            )
+            # the step's output slices: one program per width, whatever
+            # the number of sessions served
+            jax.block_until_ready(bank.slot_outputs(_Y, [0]))
             banks[w], states[w] = bank, out_state
         for w in widths:
             bank, state = banks[w], states[w]

@@ -5,8 +5,12 @@
 are served under ``jax.profiler.trace`` and the spans read back with
 ``ProfileData``: each nests as the engine's docstring draws it, none takes a
 name the benchmark harness gives its own spans, their number per tick does
-not grow with the bank, and tracing changes no output and no state.
+not grow with the bank, and tracing changes no output and no state.  The
+tick's output slices are one dispatch of one program per bank width: the
+dispatches inside ``serve.outputs`` do not grow with the bank, and a change
+in the number of sessions served compiles nothing.
 """
+import contextlib
 import glob
 from collections import Counter
 
@@ -76,17 +80,29 @@ def _serve(svc):
 
 
 def _traced(S, log_dir):
+    """The service, its outputs, its spans and its JAX dispatches (each as
+    (name, parent)) over ``_serve``, traced."""
     svc = _service(S)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     with jax.profiler.trace(str(log_dir), profiler_options=opts):
         outs = _serve(svc)
-    return svc, outs, _host_spans(log_dir)
+    spans = _host_spans(log_dir)
+    return (
+        svc, outs,
+        [(n, p) for n, p in spans if not _is_dispatch(n)],
+        [(n, p) for n, p in spans if _is_dispatch(n)],
+    )
+
+
+def _is_dispatch(name):
+    return name.startswith(("PjitFunction(", "DevicePut"))
 
 
 def _host_spans(log_dir):
-    """(name, parent) of every program or harness span on the host, the
-    parent being the innermost ``serve.*`` span around it on its thread."""
+    """(name, parent) of every program or harness span and every JAX
+    dispatch on the host that no other dispatch holds, the parent being the
+    innermost ``serve.*`` span around it on its thread."""
     path = sorted(glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True))[-1]
     found = []
     for plane in ProfileData.from_file(path).planes:
@@ -97,7 +113,9 @@ def _host_spans(log_dir):
                 (
                     (e.start_ns, e.start_ns + e.duration_ns, e.name.split("#")[0])
                     for e in line.events
-                    if e.name.startswith("serve.") or e.name.split("#")[0] in HARNESS
+                    if e.name.startswith("serve.")
+                    or e.name.split("#")[0] in HARNESS
+                    or _is_dispatch(e.name)
                 ),
                 key=lambda e: (e[0], -e[1]),
             )
@@ -105,6 +123,8 @@ def _host_spans(log_dir):
             for s, e, name in events:
                 while stack and not (stack[-1][0] <= s and e <= stack[-1][1]):
                     stack.pop()
+                if _is_dispatch(name) and any(_is_dispatch(n) for _, _, n in stack):
+                    continue  # a dispatch inside another counts once
                 parents = [n for _, _, n in stack if n.startswith("serve.")]
                 found.append((name, parents[-1] if parents else None))
                 stack.append((s, e, name))
@@ -116,6 +136,11 @@ def traced4(tmp_path_factory):
     return _traced(4, tmp_path_factory.mktemp("trace4"))
 
 
+@pytest.fixture(scope="module")
+def traced16(tmp_path_factory):
+    return _traced(16, tmp_path_factory.mktemp("trace16"))
+
+
 def test_span_names_are_the_programs_own():
     assert len(set(SPANS)) == len(SPANS)
     assert set(SPANS) == set(PARENT)
@@ -124,7 +149,7 @@ def test_span_names_are_the_programs_own():
 
 
 def test_every_span_nests_as_documented(traced4):
-    _, _, spans = traced4
+    _, _, spans, _ = traced4
     assert not [name for name, _ in spans if name in HARNESS]
     names = Counter(name for name, _ in spans)
     assert set(names) == set(SPANS)
@@ -138,14 +163,14 @@ def test_every_span_nests_as_documented(traced4):
             assert parent == PARENT[name], (name, parent)
 
 
-def test_spans_per_tick_do_not_grow_with_the_bank(traced4, tmp_path):
-    _, _, narrow = traced4
-    _, _, wide = _traced(16, tmp_path)
+def test_spans_per_tick_do_not_grow_with_the_bank(traced4, traced16):
+    _, _, narrow, _ = traced4
+    _, _, wide, _ = traced16
     assert Counter(narrow) == Counter(wide)
 
 
 def test_tracing_changes_no_output_and_no_state(traced4):
-    svc_on, outs_on, _ = traced4
+    svc_on, outs_on, _, _ = traced4
     svc_off = _service(4)
     outs_off = _serve(svc_off)
     assert [sorted(o, key=str) for o in outs_on] == [
@@ -156,3 +181,65 @@ def test_tracing_changes_no_output_and_no_state(traced4):
             np.testing.assert_array_equal(on[sid], off[sid])
     for a, b in zip(jax.tree.leaves(svc_on.state), jax.tree.leaves(svc_off.state)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_outputs_are_one_dispatch_at_any_width(traced4, traced16):
+    steps = TICKS + 1
+    counts = []
+    for _, _, _, dispatches in (traced4, traced16):
+        inside = Counter(n for n, parent in dispatches if parent == "serve.outputs")
+        assert 0 < sum(inside.values()) <= steps, inside
+        counts.append(inside)
+    assert counts[0] == counts[1]
+
+
+@contextlib.contextmanager
+def _compiles():
+    """Counts the programs lowered inside the block, as the benchmark
+    harness counts them in its window."""
+    seen = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def test_a_changing_served_count_compiles_nothing():
+    svc = _service(8)
+    # the eviction path's slot read, warmed as the benchmark harness warms it
+    jax.block_until_ready(svc.bank.slot_state(svc.state, 0))
+    svc.run_tick()
+    batch = np.random.default_rng(2).standard_normal((P, M)).astype(np.float32)
+    with _compiles() as seen:
+        served = [len(svc.run_tick())]
+        svc.evict(0)
+        served.append(len(svc.run_tick()))
+        served.append(len(svc.step({"pushed": batch})))
+        served.append(len(svc.step({"pushed": batch, 1: batch, 2: batch})))
+        served.append(len(svc.run_tick()))
+    assert served == [7, 6, 1, 3, 6]
+    assert seen == []
+
+
+def test_outputs_span_carries_sessions_and_width(tmp_path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _serve(_service(4))
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True))[-1]
+    got = [
+        dict(e.stats)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines
+        for e in line.events
+        if e.name == "serve.outputs"
+    ]
+    # three sessions pull from sources, then one batch is pushed by hand
+    assert got == [{"sessions": 3, "width": 4}] * TICKS + [{"sessions": 1, "width": 4}]

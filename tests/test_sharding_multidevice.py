@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core.easi import EASIConfig
 from repro.core.smbgd import SMBGDConfig
@@ -125,3 +126,28 @@ def test_8dev_active_mask_and_multiple_steps():
         np.asarray(st_sh.B), np.asarray(st_lo.B), rtol=1e-6, atol=1e-6
     )
     np.testing.assert_array_equal(np.asarray(st_sh.step), np.asarray(st_lo.step))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused_megakernel"])
+def test_8dev_slot_outputs_follow_the_sharded_y(fused):
+    """The served outputs' program slices a stream-sharded ``Y`` to each
+    slot's exact ``(P, n)`` values.  The mesh's axis is automatic: JAX's
+    sharding types refuse a dynamic slice of one row along an explicit
+    sharded axis, eager or jitted alike."""
+    ecfg, ocfg = _cfgs()
+    S = 2 * N_DEV
+    bank = SeparatorBank(ecfg, ocfg, n_streams=S, fused=fused)
+    key = jax.random.PRNGKey(7)
+    X = jax.random.normal(jax.random.fold_in(key, 1), (S, 8, 4))
+    if fused:
+        X = bank.pad_batch(X)
+    mesh = jax.make_mesh((N_DEV,), ("stream",), axis_types=(AxisType.Auto,))
+    state = jax.device_put(bank.init(key), bank_sharding(mesh))
+    _, Y = make_sharded_bank_step(bank, mesh)(state, X)
+    assert len(Y.sharding.device_set) == N_DEV
+    slots = [5, 0, 15, 9, 8]
+    out = bank.slot_outputs(Y, slots)
+    host = np.asarray(Y)
+    assert len(out) == len(slots)
+    for y, slot in zip(out, slots):
+        assert np.asarray(y).tobytes() == host[slot, :8, :2].tobytes()

@@ -10,6 +10,8 @@ The backend of this process is the CPU, so the code that picks the lowering
 from the backend (``repro.kernels.interpret_mode``) is steered to the compiled
 path here, in the test.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,3 +174,23 @@ def test_bank_step_dispatches_under_its_name(topo, compiled_lowering):
     hlo = step.lower(state, X, active).as_text(dialect="hlo", debug_info=True)
     assert hlo.startswith("HloModule jit_bank_step")
     assert 'op_name="smbgd_step_bank/pallas_call"' in hlo
+
+
+@pytest.mark.parametrize("n", [2, 22])
+def test_slot_outputs_compile_at_the_served_width(topo, n):
+    """One call of the served outputs' program at the cells' width (256
+    sessions, ``Y`` padded to ``(8, 128)``) compiles to one dynamic slice
+    per output, each ``(1, P, n)``, no gather of the padded rows, and no
+    temporary in HBM (a call of 256 outputs needs 1.1 MB)."""
+    from repro.stream.bank import _OUTPUTS_PER_CALL, _slot_outputs_jit
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    S, P = 256, SMBGD.batch_size
+    Y = jax.ShapeDtypeStruct((S, 8, 128), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((_OUTPUTS_PER_CALL,), jnp.int32, sharding=one_chip)
+    compiled = _slot_outputs_jit.lower(Y, idx, P=P, n=n).compile()
+    hlo = compiled.as_text()
+    assert "gather(" not in hlo
+    sizes = re.findall(r"dynamic-slice\(.*?dynamic_slice_sizes=\{([0-9,]+)\}", hlo)
+    assert sizes == [f"1,{P},{n}"] * _OUTPUTS_PER_CALL
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
