@@ -216,8 +216,12 @@ trace shows the host's phases on the device operations' clock::
       serve.backfill          free slots filled from the scheduler
       serve.pull              one block from every bound source
       serve.step              also a root when a caller pushes batches
-        serve.stage           checks, staging copy, host→device transfer
-        serve.launch          the jitted bank step's dispatch
+        serve.stage           checks, staging copy
+          serve.h2d           host→device copy of the staged batch and
+                              mask (metadata bytes=<staged bytes>)
+        serve.launch          the jitted bank step's dispatch (metadata
+                              block_s=<streams per grid cell>
+                              stream_blocks=<grid cells over the streams>)
         serve.ready           the tick timer's sync on the conv leaf
         serve.outputs         the output slices, one program (metadata
                               sessions=<k> width=<S>)
@@ -302,6 +306,7 @@ SPANS = (
     "serve.pull",
     "serve.step",
     "serve.stage",
+    "serve.h2d",
     "serve.launch",
     "serve.ready",
     "serve.outputs",
@@ -1281,9 +1286,17 @@ class SeparationService:
                 # full-result sync and is timed as-is.
                 timer = self._timer
                 timer.start()
-                X_dev, active_dev = jnp.asarray(X), jnp.asarray(active)
+                with _span("serve.h2d", bytes=X.nbytes + active.nbytes):
+                    X_dev, active_dev = jnp.asarray(X), jnp.asarray(active)
                 hp = (self._current_hp(),) if self._hp_step else ()
-            with _span("serve.launch"):
+            # the megakernel's grid over the streams (none on unfused banks)
+            block_s = self.bank.resolved_block_s
+            grid = (
+                {"block_s": block_s, "stream_blocks": self.bank.n_streams // block_s}
+                if block_s
+                else {}
+            )
+            with _span("serve.launch", **grid):
                 self.state, Y = self._step(self.state, X_dev, active_dev, *hp)
             # the batch's device copy goes once the step holds it: kept to
             # the end of the tick, it would add its size to peak memory

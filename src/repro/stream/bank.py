@@ -383,6 +383,19 @@ class SeparatorBank:
 
         return float(easi_ops.HEALTH_BLOWUP_BOUND)
 
+    @functools.cached_property
+    def resolved_block_s(self) -> Optional[int]:
+        """Streams per grid cell of the fused step's megakernel: ``block_s``
+        as set or tuned, else what ``ops.default_block_s`` resolves for this
+        width and layout; None on an unfused bank."""
+        if not self.fused:
+            return None
+        if self.block_s is not None:
+            return int(self.block_s)
+        from repro.kernels.easi_gradient import ops as easi_ops
+
+        return easi_ops.default_block_s(self.n_streams, self.layout)
+
     @property
     def _sep(self) -> Separator:
         return Separator(self.easi, self.opt, self.algorithm, self.use_pallas)

@@ -39,6 +39,7 @@ PARENT = {
     "serve.pull": "serve.run_tick",
     "serve.step": "serve.run_tick",
     "serve.stage": "serve.step",
+    "serve.h2d": "serve.stage",
     "serve.launch": "serve.step",
     "serve.ready": "serve.step",
     "serve.outputs": "serve.step",
@@ -227,19 +228,53 @@ def test_a_changing_served_count_compiles_nothing():
     assert seen == []
 
 
-def test_outputs_span_carries_sessions_and_width(tmp_path):
+@pytest.fixture(scope="module")
+def span_stats(tmp_path_factory):
+    """The service of four slots served traced, and a function from a span
+    name to the metadata of each of its spans, in order."""
+    log_dir = tmp_path_factory.mktemp("stats")
+    svc = _service(4)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
-        _serve(_service(4))
-    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True))[-1]
-    got = [
-        dict(e.stats)
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        _serve(svc)
+    path = sorted(glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True))[-1]
+    events = [
+        (e.name, dict(e.stats))
         for plane in ProfileData.from_file(path).planes
         if plane.name == "/host:CPU"
         for line in plane.lines
         for e in line.events
-        if e.name == "serve.outputs"
+        if e.name.startswith("serve.")
     ]
+    return svc, lambda name: [stats for n, stats in events if n == name]
+
+
+def test_outputs_span_carries_sessions_and_width(span_stats):
+    _, stats = span_stats
+    got = stats("serve.outputs")
     # three sessions pull from sources, then one batch is pushed by hand
     assert got == [{"sessions": 3, "width": 4}] * TICKS + [{"sessions": 1, "width": 4}]
+
+
+def test_h2d_span_carries_the_staged_bytes(span_stats):
+    """``serve.h2d`` (inside ``serve.stage``) copies the whole staging
+    buffer ``(S, P_pad, m_pad)`` f32 and the ``(S,)`` mask, whatever the
+    number of sessions served."""
+    svc, stats = span_stats
+    lay, S = svc.bank.layout, svc.bank.n_streams
+    staged = S * lay.P_pad * lay.m_pad * 4 + S
+    assert stats("serve.h2d") == [{"bytes": staged}] * (TICKS + 1)
+
+
+def test_launch_span_carries_the_grid(span_stats):
+    """``serve.launch`` names the megakernel's resolved ``block_s`` and the
+    grid's stream blocks, as ``ops.default_block_s`` resolves them."""
+    from repro.kernels.easi_gradient import ops as easi_ops
+
+    svc, stats = span_stats
+    S = svc.bank.n_streams
+    block_s = easi_ops.default_block_s(S, svc.bank.layout)
+    assert svc.bank.resolved_block_s == block_s
+    want = {"block_s": block_s, "stream_blocks": S // block_s}
+    assert stats("serve.launch") == [want] * (TICKS + 1)

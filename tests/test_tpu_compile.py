@@ -69,21 +69,21 @@ def _kernel_args(sharding, S, lay):
     )
 
 
-def _compile_kernel(topo, kind, S, *, prefetch=False, policy="f32"):
-    """Compile one megakernel at the paper shape with the default block_s;
-    returns (HLO text, resolved block_s)."""
+def _compile_kernel(topo, kind, S, *, prefetch=False, policy="f32",
+                    n=EASI.n_components, m=EASI.n_features, nonlinearity="cubic"):
+    """Compile one megakernel (the paper shape unless told) with the
+    default block_s; returns (compiled, resolved block_s)."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     lay = easi_ops.bank_layout(
-        EASI.n_components, EASI.n_features, SMBGD.batch_size,
-        interpret=False, dtype_policy=policy,
+        n, m, SMBGD.batch_size, interpret=False, dtype_policy=policy,
     )
     fn = easi_ops.smbgd_step_bank if kind == "step" else easi_ops.smbgd_probe_bank
     call = lambda *a: fn(
         *a, block_p=lay.block_p, interpret=False, prefetch=prefetch,
-        moments=True,
+        moments=True, nonlinearity=nonlinearity,
     )
-    hlo = jax.jit(call).lower(*_kernel_args(one_chip, S, lay)).compile().as_text()
-    return hlo, easi_ops.default_block_s(S, lay, interpret=False)
+    compiled = jax.jit(call).lower(*_kernel_args(one_chip, S, lay)).compile()
+    return compiled, easi_ops.default_block_s(S, lay, interpret=False)
 
 
 @pytest.mark.parametrize("policy", ["f32", "bf16"])
@@ -92,19 +92,34 @@ def _compile_kernel(topo, kind, S, *, prefetch=False, policy="f32"):
 def test_megakernel_compiles_at_default_block_s(topo, kind, prefetch, policy):
     """Step and probe, sync and prefetch, f32 and bf16 state: at S=1024 the
     default block_s compiles under the kernels' VMEM limit."""
-    hlo, block_s = _compile_kernel(
+    compiled, block_s = _compile_kernel(
         topo, kind, 1024, prefetch=prefetch, policy=policy
     )
-    assert "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in compiled.as_text()
     assert block_s % 8 == 0 and 1024 % block_s == 0
 
 
 def test_width_not_a_multiple_of_8_compiles(topo):
     """36 sessions: the default block is the whole bank (a divisor such as
     18 breaks Mosaic's sublane rule for the (block_s, 1) side channels)."""
-    hlo, block_s = _compile_kernel(topo, "step", 36)
+    compiled, block_s = _compile_kernel(topo, "step", 36)
     assert block_s == 36
-    assert "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_step_megakernel_compiles_at_256_lanes(topo):
+    """The high-density EEG cell's step: 512 sessions at m = n = 256, tanh.
+    Nothing is padded, a stream holds about 5.4 MB of VMEM, so the default
+    block_s is 8 (64 grid cells over the streams) under the 64 MiB limit,
+    and the kernel runs in place of any HBM temporary."""
+    compiled, block_s = _compile_kernel(
+        topo, "step", 512, n=256, m=256, nonlinearity="tanh"
+    )
+    lay = easi_ops.bank_layout(256, 256, SMBGD.batch_size, interpret=False)
+    assert (lay.n_pad, lay.m_pad) == (256, 256)
+    assert block_s == 8
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_sharded_step_compiles_without_collectives(topo, compiled_lowering):
@@ -176,21 +191,38 @@ def test_bank_step_dispatches_under_its_name(topo, compiled_lowering):
     assert 'op_name="smbgd_step_bank/pallas_call"' in hlo
 
 
-@pytest.mark.parametrize("n", [2, 22])
-def test_slot_outputs_compile_at_the_served_width(topo, n):
-    """One call of the served outputs' program at the cells' width (256
-    sessions, ``Y`` padded to ``(8, 128)``) compiles to one dynamic slice
-    per output, each ``(1, P, n)``, no gather of the padded rows, and no
-    temporary in HBM (a call of 256 outputs needs 1.1 MB)."""
+def _slot_outputs_compiled(topo, S, lanes, n):
+    """One call of the served outputs' program, ``Y (S, 8, lanes)``,
+    compiled; asserts one dynamic slice per output, each ``(1, P, n)``, and
+    no gather of the padded rows."""
     from repro.stream.bank import _OUTPUTS_PER_CALL, _slot_outputs_jit
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    S, P = 256, SMBGD.batch_size
-    Y = jax.ShapeDtypeStruct((S, 8, 128), jnp.float32, sharding=one_chip)
+    P = SMBGD.batch_size
+    Y = jax.ShapeDtypeStruct((S, 8, lanes), jnp.float32, sharding=one_chip)
     idx = jax.ShapeDtypeStruct((_OUTPUTS_PER_CALL,), jnp.int32, sharding=one_chip)
     compiled = _slot_outputs_jit.lower(Y, idx, P=P, n=n).compile()
     hlo = compiled.as_text()
     assert "gather(" not in hlo
     sizes = re.findall(r"dynamic-slice\(.*?dynamic_slice_sizes=\{([0-9,]+)\}", hlo)
     assert sizes == [f"1,{P},{n}"] * _OUTPUTS_PER_CALL
+    return compiled
+
+
+@pytest.mark.parametrize("n", [2, 22])
+def test_slot_outputs_compile_at_the_served_width(topo, n):
+    """One call of the served outputs' program at the cells' width (256
+    sessions, ``Y`` padded to ``(8, 128)``) compiles to one dynamic slice
+    per output, each ``(1, P, n)``, no gather of the padded rows, and no
+    temporary in HBM (a call of 256 outputs needs 1.1 MB)."""
+    compiled = _slot_outputs_compiled(topo, 256, 128, n)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_slot_outputs_compile_at_256_lanes(topo):
+    """The high-density EEG cell's outputs: 512 sessions at ``n = 256``,
+    unpadded.  A call's 128 outputs of ``(8, 256)`` f32 (1 MB) are written
+    straight to its results: no temporary in HBM."""
+    compiled = _slot_outputs_compiled(topo, 512, 256, 256)
+    assert compiled.memory_analysis().output_size_in_bytes >= 128 * 8 * 256 * 4
     assert compiled.memory_analysis().temp_size_in_bytes == 0
