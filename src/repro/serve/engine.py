@@ -223,8 +223,9 @@ trace shows the host's phases on the device operations' clock::
                               block_s=<streams per grid cell>
                               stream_blocks=<grid cells over the streams>)
         serve.ready           the tick timer's sync on the conv leaf
-        serve.outputs         the output slices, one program (metadata
-                              sessions=<k> width=<S>)
+        serve.outputs         one host read of the step's Y, cut to
+                              per-session views (metadata sessions=<k>
+                              width=<S> bytes=<bytes read to the host>)
         serve.moments         moments readback and controller
         serve.health          the health sweep
         serve.policy          the convergence and drift sweep
@@ -549,9 +550,10 @@ class SeparationService:
     buffer (``bank.layout``; reused every tick — stale slots are masked
     inactive and the padding region is never written, so no re-zeroing), the
     jitted step donates the persistent padded state back to the kernel
-    outputs (accelerator backends), and per-session slices are cut from the
-    padded Y at return — steady-state serving allocates no device state per
-    tick (the host→device transfer of the staging buffer remains).
+    outputs (accelerator backends), and the padded Y is read to the host once
+    at return and cut there into per-session views — steady-state serving
+    allocates no device state per tick (the host→device transfer of the
+    staging buffer and the device→host read of Y remain).
 
     Metrics (the backpressure/observability hook): ``metrics`` (a dict, also
     callable as ``svc.metrics()``) reports per-tick TIME-TO-READY latency
@@ -1259,17 +1261,20 @@ class SeparationService:
         self._backfill()
         return record
 
-    def step(self, batches: Dict[Hashable, jnp.ndarray]) -> Dict[Hashable, jnp.ndarray]:
+    def step(self, batches: Dict[Hashable, jnp.ndarray]) -> Dict[Hashable, np.ndarray]:
         """Advance every session that sent data this tick.
 
         ``batches`` maps session_id → ``(P, m)`` mini-batch.  Sessions without
         data (and free slots) are masked inactive — state untouched.  Returns
-        session_id → separated ``(P, n)`` outputs from one fused bank step.
+        session_id → separated ``(P, n)`` outputs from one fused bank step,
+        as host ``np.ndarray`` views of one fresh ``(S, P, n)`` array.
 
         On a fused bank the staging buffer is allocated block-aligned
         (``(S, P_pad, m_pad)``) so the jitted step consumes it with no
-        re-padding copy; one compiled program cuts every session's ``(P, n)``
-        output from the step's result (``SeparatorBank.slot_outputs``).
+        re-padding copy; the step's ``Y`` is read to the host once and cut
+        there (``SeparatorBank.slot_outputs``).  That read waits for the
+        step, so every tick that returns outputs syncs, whatever
+        ``SLOPolicy.sync_every`` says.
         """
         if not batches:
             return {}
@@ -1313,13 +1318,13 @@ class SeparationService:
                 st = self._stats[sid]
                 st.ticks += 1
                 st.samples += P
-            # slice outputs BEFORE any auto-eviction mutates the slot map:
+            # cut outputs BEFORE any auto-eviction mutates the slot map:
             # evicted sessions still receive this tick's separated output.
-            # One compiled program per bank width cuts every served slot's
-            # output (slot indices as a traced operand): one dispatch per
-            # 128 sessions, and no compile when the served count changes
+            # One host read of the whole Y, no dispatch and no program, so
+            # neither the served count nor the width compiles anything
             with _span(
-                "serve.outputs", sessions=len(batches), width=self.bank.n_streams
+                "serve.outputs", sessions=len(batches),
+                width=self.bank.n_streams, bytes=Y.nbytes,
             ):
                 ys = self.bank.slot_outputs(
                     Y, [self._slot_of[sid] for sid in batches]
@@ -2189,11 +2194,8 @@ class SeparationService:
             args = (state, jnp.asarray(X), jnp.asarray(active))
             if self._hp_step:
                 args = args + (bank._bank_hyperparams(),)
-            out_state, _Y = fn(*args)
+            out_state, _ = fn(*args)
             jax.block_until_ready(out_state.conv)
-            # the step's output slices: one program per width, whatever
-            # the number of sessions served
-            jax.block_until_ready(bank.slot_outputs(_Y, [0]))
             banks[w], states[w] = bank, out_state
         for w in widths:
             bank, state = banks[w], states[w]
@@ -2414,14 +2416,15 @@ class SeparationService:
             self.shrink(decision.target, reason=decision.reason)
 
     # -- scheduler-driven ingestion ---------------------------------------
-    def run_tick(self) -> Dict[Hashable, jnp.ndarray]:
+    def run_tick(self) -> Dict[Hashable, np.ndarray]:
         """One pull tick: backfill free slots from the scheduler, pull a
         channel-major ``(m, P)`` block from every active session's bound
         ``SignalSource``, advance them all with ONE fused bank step, evict
         sessions whose source drained (reason ``"exhausted"``), and probe
         parked and quarantined sessions out of band.  Returns session_id →
-        separated ``(P, n)`` outputs (sessions without a source are skipped —
-        push their batches through ``step`` instead; both modes mix freely).
+        separated ``(P, n)`` host outputs, as ``step`` does (sessions without
+        a source are skipped — push their batches through ``step`` instead;
+        both modes mix freely).
 
         Per-session fault isolation: a source raising anything other than
         ``SourceExhausted`` (transient I/O error, stall past a

@@ -17,8 +17,10 @@ wires it into every tick:
     backend regardless of ``block_ticks``.  ``sync_every=k`` samples the sync
     1-in-k: only synced ticks are *timed* (fed to the sketch, deadline-
     checked, counted in ``mean_tick_s``); the k−1 unsynced ticks between them
-    run dispatch-deep with no latency record at all — sampled mode trades
-    telemetry density for zero sync overhead, never fabricates numbers.
+    keep no latency record at all — sampled mode trades telemetry density
+    for fewer timer syncs, never fabricates numbers.  A served tick still
+    waits for its step: ``SeparationService.step`` reads the step's outputs
+    to the host.
   * ``LatencySketch`` — streaming p50/p99/p999 over tick latencies, two
     horizons at once: an exact sliding window (last ``window`` timed ticks,
     ``np.quantile`` on demand) and a bounded-memory lifetime histogram with

@@ -201,24 +201,6 @@ def _resize_rows_jit(new_S, B, H, step, conv, health, moments):
     )
 
 
-# Outputs cut per call of ``_slot_outputs_jit``.  On TPU v5e the compiled
-# program's code, which the device holds in HBM, grows by about 4.7 KB per
-# output: 1.13 MB at 256 outputs, +0.96% on the peak of a served
-# 256-session bank at m=4, n=2; 0.65 MB and +0.54% at 128, for one more
-# dispatch per 128 sessions.  Past 128 outputs it also takes an HBM
-# temporary.
-_OUTPUTS_PER_CALL = 128
-
-
-@functools.partial(jax.jit, static_argnames=("P", "n"))
-def _slot_outputs_jit(Y, idx, P, n):
-    """Output ``i`` is ``Y[idx[i], :P, :n]``: one dynamic slice per entry,
-    straight from ``Y``, never a gather at the padded width.  The program's
-    shapes follow ``Y`` and ``idx`` alone, so one compile per bank width
-    serves every count of served sessions."""
-    return tuple(Y[idx[i], :P, :n] for i in range(idx.shape[0]))
-
-
 @dataclasses.dataclass(frozen=True, eq=False)
 class SeparatorBank:
     """S-stream separation engine; same ``algorithm`` knob as ``Separator``.
@@ -511,22 +493,20 @@ class SeparatorBank:
         either way)."""
         return jnp.asarray(slot, jnp.int32)
 
-    def slot_outputs(self, Y: jnp.ndarray, slots) -> Tuple[jnp.ndarray, ...]:
+    def slot_outputs(self, Y: jnp.ndarray, slots) -> Tuple[np.ndarray, ...]:
         """The logical ``(P, n)`` outputs of one step for ``slots``, in
-        order, cut from the step's ``Y`` (padded or not) by one compiled
-        program: one call per ``min(S, 128)`` slots.  The slot vector is a
-        traced operand padded with slot 0 to that length, so the program
-        depends on the bank's width alone; the values are those of
-        ``Y[slot, :P, :n]``."""
-        k = min(Y.shape[0], _OUTPUTS_PER_CALL)
-        idx = np.zeros((-(-len(slots) // k) * k,), np.int32)
-        idx[: len(slots)] = slots
-        out = []
-        for i in range(0, len(idx), k):
-            out += _slot_outputs_jit(
-                Y, idx[i : i + k], P=self.opt.batch_size, n=self.easi.n_components
-            )
-        return tuple(out[: len(slots)])
+        order: host views of one fresh ``(S, P, n)`` array, bit for bit
+        ``np.asarray(Y)[slot, :P, :n]``.
+
+        ``Y`` (padded or not, sharded or not) is read to the host once,
+        whole: ``S·P_pad·n_pad`` elements, whichever slots are served (1 MB
+        at 256 paper-shape slots, 4 MB at 512 slots of ``n = 256``).  The
+        read waits for the step, so it syncs the tick.  The logical block is
+        then copied out, so a caller keeping a view pins ``S·P·n`` elements,
+        never the padded rows, and no later tick writes over it."""
+        P, n = self.opt.batch_size, self.easi.n_components
+        host = np.array(np.asarray(Y)[:, :P, :n])
+        return tuple(host[s] for s in slots)
 
     def init_slot(self, state: BankState, slot, key: jax.Array) -> BankState:
         """Reset one stream slot to a fresh session (admission path).  On a

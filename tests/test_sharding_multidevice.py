@@ -128,12 +128,18 @@ def test_8dev_active_mask_and_multiple_steps():
     np.testing.assert_array_equal(np.asarray(st_sh.step), np.asarray(st_lo.step))
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused_megakernel"])
-def test_8dev_slot_outputs_follow_the_sharded_y(fused):
-    """The served outputs' program slices a stream-sharded ``Y`` to each
-    slot's exact ``(P, n)`` values.  The mesh's axis is automatic: JAX's
-    sharding types refuse a dynamic slice of one row along an explicit
-    sharded axis, eager or jitted alike."""
+@pytest.mark.parametrize(
+    "fused, axis",
+    [
+        pytest.param(False, AxisType.Auto, id="vmap"),
+        pytest.param(True, AxisType.Auto, id="fused_megakernel"),
+        pytest.param(True, AxisType.Explicit, id="fused_megakernel_explicit"),
+    ],
+)
+def test_8dev_slot_outputs_follow_the_sharded_y(fused, axis):
+    """The served outputs are each slot's exact ``(P, n)`` values of a
+    stream-sharded ``Y``, on a mesh whose axis is automatic or explicit:
+    the one host read gathers the shards."""
     ecfg, ocfg = _cfgs()
     S = 2 * N_DEV
     bank = SeparatorBank(ecfg, ocfg, n_streams=S, fused=fused)
@@ -141,7 +147,7 @@ def test_8dev_slot_outputs_follow_the_sharded_y(fused):
     X = jax.random.normal(jax.random.fold_in(key, 1), (S, 8, 4))
     if fused:
         X = bank.pad_batch(X)
-    mesh = jax.make_mesh((N_DEV,), ("stream",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((N_DEV,), ("stream",), axis_types=(axis,))
     state = jax.device_put(bank.init(key), bank_sharding(mesh))
     _, Y = make_sharded_bank_step(bank, mesh)(state, X)
     assert len(Y.sharding.device_set) == N_DEV
@@ -150,4 +156,5 @@ def test_8dev_slot_outputs_follow_the_sharded_y(fused):
     host = np.asarray(Y)
     assert len(out) == len(slots)
     for y, slot in zip(out, slots):
+        assert type(y) is np.ndarray
         assert np.asarray(y).tobytes() == host[slot, :8, :2].tobytes()

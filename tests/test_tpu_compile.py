@@ -10,8 +10,6 @@ The backend of this process is the CPU, so the code that picks the lowering
 from the backend (``repro.kernels.interpret_mode``) is steered to the compiled
 path here, in the test.
 """
-import re
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -189,40 +187,3 @@ def test_bank_step_dispatches_under_its_name(topo, compiled_lowering):
     hlo = step.lower(state, X, active).as_text(dialect="hlo", debug_info=True)
     assert hlo.startswith("HloModule jit_bank_step")
     assert 'op_name="smbgd_step_bank/pallas_call"' in hlo
-
-
-def _slot_outputs_compiled(topo, S, lanes, n):
-    """One call of the served outputs' program, ``Y (S, 8, lanes)``,
-    compiled; asserts one dynamic slice per output, each ``(1, P, n)``, and
-    no gather of the padded rows."""
-    from repro.stream.bank import _OUTPUTS_PER_CALL, _slot_outputs_jit
-
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    P = SMBGD.batch_size
-    Y = jax.ShapeDtypeStruct((S, 8, lanes), jnp.float32, sharding=one_chip)
-    idx = jax.ShapeDtypeStruct((_OUTPUTS_PER_CALL,), jnp.int32, sharding=one_chip)
-    compiled = _slot_outputs_jit.lower(Y, idx, P=P, n=n).compile()
-    hlo = compiled.as_text()
-    assert "gather(" not in hlo
-    sizes = re.findall(r"dynamic-slice\(.*?dynamic_slice_sizes=\{([0-9,]+)\}", hlo)
-    assert sizes == [f"1,{P},{n}"] * _OUTPUTS_PER_CALL
-    return compiled
-
-
-@pytest.mark.parametrize("n", [2, 22])
-def test_slot_outputs_compile_at_the_served_width(topo, n):
-    """One call of the served outputs' program at the cells' width (256
-    sessions, ``Y`` padded to ``(8, 128)``) compiles to one dynamic slice
-    per output, each ``(1, P, n)``, no gather of the padded rows, and no
-    temporary in HBM (a call of 256 outputs needs 1.1 MB)."""
-    compiled = _slot_outputs_compiled(topo, 256, 128, n)
-    assert compiled.memory_analysis().temp_size_in_bytes == 0
-
-
-def test_slot_outputs_compile_at_256_lanes(topo):
-    """The high-density EEG cell's outputs: 512 sessions at ``n = 256``,
-    unpadded.  A call's 128 outputs of ``(8, 256)`` f32 (1 MB) are written
-    straight to its results: no temporary in HBM."""
-    compiled = _slot_outputs_compiled(topo, 512, 256, 256)
-    assert compiled.memory_analysis().output_size_in_bytes >= 128 * 8 * 256 * 4
-    assert compiled.memory_analysis().temp_size_in_bytes == 0
