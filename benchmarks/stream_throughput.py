@@ -23,7 +23,7 @@ trajectory is recorded run over run.
 
     PYTHONPATH=src python benchmarks/stream_throughput.py [--quick]
     PYTHONPATH=src python benchmarks/stream_throughput.py --autotune   # 2-D sweep:
-        (block_p, block_s) x prefetch, bf16 measured at the winning
+        (block_p, block_s), bf16 measured at the winning
         geometry; winners persist to AUTOTUNE.json (stream.autotune),
         which SeparatorBank loads by default
     PYTHONPATH=src python benchmarks/stream_throughput.py --autotune-smoke  # CI:
@@ -228,12 +228,12 @@ def bench_streams(
     )
     measured_bytes = _measured_tick_bytes(fstep, state0f, Xp, act)
 
-    # bf16 storage + prefetch at the SAME resolved geometry as the f32 fused
-    # bank — the reduced-footprint serving configuration
+    # bf16 storage at the SAME resolved geometry as the f32 fused bank — the
+    # reduced-footprint serving configuration
     fused_bf = SeparatorBank(
         ecfg, ocfg, n_streams=S, fused=True,
         block_p=lay.block_p, block_s=fused.block_s,
-        dtype_policy="bf16", prefetch=True, autotune=False,
+        dtype_policy="bf16", autotune=False,
     )
     fstep_bf = fused_bf.make_step()
     state0bf = fused_bf.init(key)
@@ -265,16 +265,15 @@ def bench_streams(
     row = {
         "S": S, "P": P, "m": m, "n": n, "n_ticks": n_ticks,
         "fused_block_p": lay.block_p,
-        "fused_prefetch": bool(fused.prefetch),
         "bank_tick_s": t_bank,
         "bank_pallas_tick_s": t_pallas,
         "fused_tick_s": t_fused,
-        "fused_bf16_prefetch_tick_s": t_fused_bf,
+        "fused_bf16_tick_s": t_fused_bf,
         "loop_tick_s": t_loop,
         "bank_samples_per_s": samples_per_tick / t_bank,
         "bank_pallas_samples_per_s": samples_per_tick / t_pallas,
         "fused_samples_per_s": samples_per_tick / t_fused,
-        "fused_bf16_prefetch_samples_per_s": samples_per_tick / t_fused_bf,
+        "fused_bf16_samples_per_s": samples_per_tick / t_fused_bf,
         "loop_samples_per_s": samples_per_tick / t_loop,
         "bank_over_loop": t_loop / t_bank,
         "fused_over_bank_pallas": t_pallas / t_fused,
@@ -294,7 +293,7 @@ def bench_streams(
         f"streams,S={S},bank={row['bank_samples_per_s']:.3g}sps"
         f",pr1_pallas={row['bank_pallas_samples_per_s']:.3g}sps"
         f",fused={row['fused_samples_per_s']:.3g}sps"
-        f",bf16+pf={row['fused_bf16_prefetch_samples_per_s']:.3g}sps"
+        f",bf16={row['fused_bf16_samples_per_s']:.3g}sps"
         f",loop={row['loop_samples_per_s']:.3g}sps"
         f",bank/loop={row['bank_over_loop']:.1f}x"
         f",fused/pr1={row['fused_over_bank_pallas']:.2f}x"
@@ -314,8 +313,8 @@ def autotune_bank(
     reps: int = 2,
     write_cache: bool = True,
 ) -> List[Dict[str, float]]:
-    """2-D ``(block_p, block_s)`` sweep of the megakernel, toggling prefetch
-    at every geometry, with bf16 storage measured at the winning geometry.
+    """2-D ``(block_p, block_s)`` sweep of the megakernel, with bf16 storage
+    measured at the winning geometry.
 
     Times ONLY the fused path (the other engines don't depend on the tile
     geometry).  The winner persists to the autotune cache (``AUTOTUNE.json``,
@@ -330,10 +329,10 @@ def autotune_bank(
     X = jax.random.normal(jax.random.fold_in(key, 1), (S, P, m))
     act = jnp.ones((S,), jnp.int32)
 
-    def time_cfg(bp, bs, prefetch, policy=None):
+    def time_cfg(bp, bs, policy=None):
         fused = SeparatorBank(
             ecfg, ocfg, n_streams=S, fused=True,
-            block_p=bp, block_s=bs, prefetch=prefetch,
+            block_p=bp, block_s=bs,
             dtype_policy=policy, autotune=False,
         )
         fstep = fused.make_step()
@@ -351,19 +350,15 @@ def autotune_bank(
     rows = []
     for bp in bp_candidates:
         for bs in bs_candidates:
-            for pf in (False, True):
-                t = time_cfg(bp, bs, pf)
-                rows.append({
-                    "autotune": True, "S": S, "P": P, "m": m, "n": n,
-                    "block_p": bp, "block_s": bs, "prefetch": pf,
-                    "fused_tick_s": t,
-                })
+            rows.append({
+                "autotune": True, "S": S, "P": P, "m": m, "n": n,
+                "block_p": bp, "block_s": bs,
+                "fused_tick_s": time_cfg(bp, bs),
+            })
     best = min(rows, key=lambda r: r["fused_tick_s"])
     # bf16 at the winning geometry: recorded for the capacity story, never
     # auto-applied (precision stays a caller decision)
-    t_bf16 = time_cfg(
-        best["block_p"], best["block_s"], best["prefetch"], "bf16"
-    )
+    t_bf16 = time_cfg(best["block_p"], best["block_s"], "bf16")
     lay_f32 = easi_ops.bank_layout(n, m, P, block_p=best["block_p"])
     lay_bf16 = easi_ops.bank_layout(
         n, m, P, block_p=best["block_p"], dtype_policy="bf16"
@@ -371,7 +366,6 @@ def autotune_bank(
     entry = {
         "block_p": best["block_p"],
         "block_s": best["block_s"],
-        "prefetch": best["prefetch"],
         "fused_tick_s": best["fused_tick_s"],
         "bf16_fused_tick_s": t_bf16,
         "persistent_bytes_per_session": lay_f32.persistent_bytes_per_session,
@@ -383,7 +377,7 @@ def autotune_bank(
         print(f"autotune: wrote {autotune_lib.cache_key(S, P, m, n)} → {path}")
     print(
         f"autotune,S={S},P={P}: best block_p={best['block_p']} "
-        f"block_s={best['block_s']} prefetch={best['prefetch']} "
+        f"block_s={best['block_s']} "
         f"({best['fused_tick_s']*1e6:.1f}us/tick; bf16 {t_bf16*1e6:.1f}us)"
     )
     return rows
@@ -1564,7 +1558,7 @@ def autotune_smoke(S: int = SMOKE_S, P: int = 32, m: int = 4, n: int = 2) -> int
         )
         return 1
     failed = False
-    for field in ("block_p", "block_s", "prefetch",
+    for field in ("block_p", "block_s",
                   "persistent_bytes_per_session",
                   "bf16_persistent_bytes_per_session"):
         if field not in entry:
@@ -1576,14 +1570,12 @@ def autotune_smoke(S: int = SMOKE_S, P: int = 32, m: int = 4, n: int = 2) -> int
     ecfg = EASIConfig(n_components=n, n_features=m, mu=1e-3)
     ocfg = SMBGDConfig(batch_size=P, mu=1e-3, beta=0.9, gamma=0.5)
     bank = SeparatorBank(ecfg, ocfg, n_streams=S, fused=True)  # autotune=True
-    resolved = (bank.block_p, bank.block_s, bool(bank.prefetch))
-    recorded = (
-        int(entry["block_p"]), int(entry["block_s"]), bool(entry["prefetch"])
-    )
+    resolved = (bank.block_p, bank.block_s)
+    recorded = (int(entry["block_p"]), int(entry["block_s"]))
     if resolved != recorded:
         print(
             f"autotune-smoke: FAIL — default bank resolved "
-            f"(block_p, block_s, prefetch)={resolved} but {ckey!r} records "
+            f"(block_p, block_s)={resolved} but {ckey!r} records "
             f"{recorded}; cache resolution is broken or the key is stale"
         )
         failed = True
@@ -1673,7 +1665,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true", help="S ≤ 64, fewer reps (CI)")
     ap.add_argument("--autotune", action="store_true",
-                    help="2-D (block_p, block_s) x prefetch sweep at S=8,64; "
+                    help="2-D (block_p, block_s) sweep at S=8,64; "
                          "persists winners to AUTOTUNE.json")
     ap.add_argument("--autotune-smoke", action="store_true",
                     help="CI gate: AUTOTUNE.json fresh for the S=8 key and no "

@@ -116,7 +116,7 @@ def serve_phase(
     print(
         f"bank: sessions={S} m={lay.m} n={lay.n} P={lay.P} "
         f"m_pad={lay.m_pad} n_pad={lay.n_pad} block_p={lay.block_p} "
-        f"block_s={block_s} prefetch={bool(bank.prefetch)} "
+        f"block_s={block_s} "
         f"persistent_bytes_per_session={lay.persistent_bytes_per_session}"
     )
     svc = SeparationService(bank, seed=seed, health_policy=HealthPolicy())

@@ -101,12 +101,7 @@ Memory-system knobs (the bank's bandwidth/capacity levers; all optional):
   drops 520 → 264 bytes for the paper's 4→2 shape.  Per-stream hyperparameter
   rows stay f32 regardless of policy.  The default (``None``) follows
   ``easi.dtype`` so existing configs keep their storage contract.
-* ``SeparatorBank(..., prefetch=True)`` double-buffers the X mini-batch DMA in
-  the fused megakernel: while stream-block t computes, t+1's tile is already
-  in flight.  Bit-identical to the sync path (tested); it's a real-TPU
-  latency-hiding win — on CPU interpret mode it just adds bookkeeping, so
-  leave it off locally.
-* Geometry (``block_p``, ``block_s``, prefetch) resolves from the checked-in
+* Geometry (``block_p``, ``block_s``) resolves from the checked-in
   ``AUTOTUNE.json`` when the bank's (S, P, m, n, backend) key was swept —
   run ``benchmarks/stream_throughput.py --autotune`` once per deployment
   shape to refresh it.  Explicitly-passed knobs always win, and

@@ -15,7 +15,7 @@ import jax
 
 from repro.configs.registry import get_config
 from repro.models.model import init_params
-from repro.serve.engine import Engine, ServeConfig
+from repro.launch.serve import Engine, ServeConfig
 
 
 def main():

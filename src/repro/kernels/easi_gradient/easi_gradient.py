@@ -23,25 +23,6 @@ same ``(streams, P-tiles)`` grid, but each grid step also computes its tile of
 ``Y = X Bᵀ`` in VMEM (X never leaves the kernel as Y in HBM until the output
 write), and each stream's LAST tile performs the SMBGD commit in-register:
 
-``prefetch=True`` swaps the X operand's block pipeline for an explicit
-double-buffered DMA: X stays in ``pl.ANY`` (HBM on TPU) and the kernel
-overlaps the NEXT tile's ``make_async_copy`` with the CURRENT tile's gradient
-fold — the paper's "compute never waits on memory" pipelining one level
-deeper than BlockSpec auto-pipelining, with the prefetch window crossing
-stream-block boundaries (the last tile of stream-block s prefetches tile 0 of
-stream-block s+1, so the only un-overlapped DMA is the very first one).  The
-synchronous path stays the fallback/oracle: on the interpret path the two are
-bit-identical (tested), so prefetch is purely a memory-system knob.
-
-Reduced-precision persistent state rides the same launches for free: the
-kernels cast every operand to f32 at load (``.astype`` below) and back to the
-output ref's dtype at commit, so a bank whose ``B``/``Ĥ`` live in bf16 (see
-``ops.BankLayout.dtype_policy``) runs the gradient fold and the commit
-accumulation entirely in f32 — casts happen ONLY at the load/commit
-boundaries, and frozen (inactive) slots round-trip bf16→f32→bf16 exactly.
-Every contraction runs at ``Precision.HIGHEST`` (``_dot``): a TPU otherwise
-rounds f32 matmul operands to bf16, and the paper's datapath is f32.
-
     Ĥ' = γ̂·Ĥ + Σ_tiles S_tile      (γ̂ gated to 0 where step == 0)
     B' = B + Ĥ'·B ;  step' = step + 1
 
@@ -58,7 +39,18 @@ launch, and ``active (S, 1)`` freezes evicted/idle slots in-kernel (their
 ``block_s`` streams ride each grid cell as a leading batch dimension of every
 block (batched ``dot_general``s inside the cell), so the grid is
 ``(S / block_s, P / block_p)`` — per-cell launch/loop overhead amortizes over
-the stream block while the math stays per-stream independent.
+the stream block while the math stays per-stream independent.  The X, W and
+Y tiles ride Pallas's block pipeline, which double-buffers each of them: the
+next tile's DMA overlaps the current tile's fold.
+
+Reduced-precision persistent state rides the same launches for free: the
+kernels cast every operand to f32 at load (``.astype`` below) and back to the
+output ref's dtype at commit, so a bank whose ``B``/``Ĥ`` live in bf16 (see
+``ops.BankLayout.dtype_policy``) runs the gradient fold and the commit
+accumulation entirely in f32 — casts happen ONLY at the load/commit
+boundaries, and frozen (inactive) slots round-trip bf16→f32→bf16 exactly.
+Every contraction runs at ``Precision.HIGHEST`` (``_dot``): a TPU otherwise
+rounds f32 matmul operands to bf16, and the paper's datapath is f32.
 
 Layout notes (TPU target; validated on CPU via interpret=True):
   * last dims (n for Y/Ĥ, m for X/B) are padded to a multiple of 128 (lane
@@ -137,6 +129,11 @@ MOMENT_LEAVES = 2  # [Σy², Σy⁴]
 # HIGHEST precision) still take 8 streams per block.  ``ops.default_block_s``
 # budgets against this number.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+# ``pallas_call`` compiler parameters of the step and probe megakernels: the
+# VMEM limit above, which the default ``block_s`` is budgeted against.
+# ``dimension_semantics`` stays unset.  The interpreter ignores both.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _reduce_rows(op, a):
@@ -316,8 +313,7 @@ def _commit_streams(
     with_moments: bool,
     blowup: float,
 ):
-    """The SMBGD commit tail shared by the sync and prefetch step kernels:
-    fold the accumulated gradient into ``Ĥ'``/``B'``/``step'``/``conv'`` for
+    """The step kernel's SMBGD commit tail: fold the accumulated gradient into ``Ĥ'``/``B'``/``step'``/``conv'`` for
     one stream-block.  ``b`` is the block's B already cast to f32; all math
     runs in f32 and casts back to the output refs' (storage) dtype only at
     the final writes — frozen slots round-trip bf16→f32→bf16 exactly.
@@ -476,104 +472,6 @@ def _smbgd_step_bank_kernel(
         )
 
 
-def _x_tile_dma(x_hbm, xbuf_ref, sem_ref, slot, t, n_tiles, block_s, block_p):
-    """Async-copy descriptor for global tile ``t``'s X block (stream-block
-    ``t // n_tiles``, tile ``t % n_tiles``) into double-buffer ``slot``."""
-    sb = t // n_tiles
-    i = jax.lax.rem(t, n_tiles)
-    return pltpu.make_async_copy(
-        x_hbm.at[
-            pl.ds(sb * block_s, block_s), pl.ds(i * block_p, block_p), :
-        ],
-        xbuf_ref.at[slot],
-        sem_ref.at[slot],
-    )
-
-
-def _smbgd_step_bank_kernel_prefetch(
-    x_hbm,
-    w_ref,
-    b_ref,
-    h_ref,
-    step_ref,
-    gamma_hat_ref,
-    active_ref,
-    conv_ref,
-    y_ref,
-    b_out_ref,
-    h_out_ref,
-    step_out_ref,
-    conv_out_ref,
-    health_out_ref,
-    moment_out_ref,
-    acc_ref,
-    ybad_ref,
-    mom_ref,
-    xbuf_ref,
-    sem_ref,
-    *,
-    nonlin: str,
-    n_tiles: int,
-    n_sblocks: int,
-    block_s: int,
-    block_p: int,
-    with_health: bool,
-    with_moments: bool,
-    blowup: float,
-):
-    """Double-buffered variant of ``_smbgd_step_bank_kernel``: X rides in
-    ``pl.ANY`` (HBM) and each grid step starts the NEXT tile's DMA before
-    folding the CURRENT tile, alternating two VMEM buffers.  The prefetch
-    window runs over the GLOBAL tile counter ``t = sb·n_tiles + i``, so it
-    crosses stream-block boundaries — only tile 0 of the whole launch pays an
-    un-overlapped DMA.  Everything downstream of the X load is byte-for-byte
-    the synchronous kernel (bit-identity on the interpret path is tested)."""
-    sb = pl.program_id(0)
-    i = pl.program_id(1)
-    t = sb * n_tiles + i  # global tile counter — the prefetch clock
-    total = n_sblocks * n_tiles
-
-    def dma(slot, t_idx):
-        return _x_tile_dma(
-            x_hbm, xbuf_ref, sem_ref, slot, t_idx, n_tiles, block_s, block_p
-        )
-
-    @pl.when(t == 0)
-    def _warmup():  # the one DMA nothing can hide
-        dma(0, 0).start()
-
-    @pl.when(t + 1 < total)
-    def _prefetch_next():  # overlap the next tile's DMA with this fold
-        dma(jax.lax.rem(t + 1, 2), t + 1).start()
-
-    dma(jax.lax.rem(t, 2), t).wait()
-    x = xbuf_ref[jax.lax.rem(t, 2)].astype(jnp.float32)  # (bs, bp, m)
-    b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
-    y_ref[...] = y.astype(y_ref.dtype)
-    w = w_ref[...].astype(jnp.float32)
-    s_tile = _fold_tile_batched(y, w, nonlin)
-    _fold_ybad_tile(y, ybad_ref, i, with_health)
-    _fold_moment_tile(y, mom_ref, i, with_moments)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = s_tile
-
-    @pl.when(i > 0)
-    def _acc():
-        acc_ref[...] += s_tile
-
-    @pl.when(i == n_tiles - 1)
-    def _commit():
-        _commit_streams(
-            b, h_ref, step_ref, gamma_hat_ref, active_ref, conv_ref,
-            b_out_ref, h_out_ref, step_out_ref, conv_out_ref, health_out_ref,
-            moment_out_ref, acc_ref, ybad_ref, mom_ref,
-            with_health=with_health, with_moments=with_moments, blowup=blowup,
-        )
-
-
 def _smbgd_probe_bank_kernel(
     x_ref,
     w_ref,
@@ -649,8 +547,7 @@ def _probe_streams(
     with_moments: bool,
     blowup: float,
 ):
-    """The freeze-only probe tail shared by the sync and prefetch probe
-    kernels: the conv statistic a commit WOULD produce, and nothing else.
+    """The probe kernel's freeze-only tail: the conv statistic a commit WOULD produce, and nothing else.
     ``with_health`` additionally reports the health word that commit WOULD
     have raised (from the virtual ``B' = B + ΔB``) — quarantined sessions
     are probed for sanity through the same launch that probes parked ones
@@ -677,81 +574,6 @@ def _probe_streams(
     conv_out_ref[...] = jnp.where(active, delta, conv_prev)
 
 
-def _smbgd_probe_bank_kernel_prefetch(
-    x_hbm,
-    w_ref,
-    b_ref,
-    h_ref,
-    step_ref,
-    gamma_hat_ref,
-    active_ref,
-    conv_ref,
-    conv_out_ref,
-    health_out_ref,
-    moment_out_ref,
-    acc_ref,
-    ybad_ref,
-    mom_ref,
-    xbuf_ref,
-    sem_ref,
-    *,
-    nonlin: str,
-    n_tiles: int,
-    n_sblocks: int,
-    block_s: int,
-    block_p: int,
-    with_health: bool,
-    with_moments: bool,
-    blowup: float,
-):
-    """Double-buffered variant of ``_smbgd_probe_bank_kernel`` — the same
-    global-tile-counter prefetch window as the step kernel's prefetch
-    variant, with the freeze-only probe tail (no ``Y``/state writes)."""
-    sb = pl.program_id(0)
-    i = pl.program_id(1)
-    t = sb * n_tiles + i
-    total = n_sblocks * n_tiles
-
-    def dma(slot, t_idx):
-        return _x_tile_dma(
-            x_hbm, xbuf_ref, sem_ref, slot, t_idx, n_tiles, block_s, block_p
-        )
-
-    @pl.when(t == 0)
-    def _warmup():
-        dma(0, 0).start()
-
-    @pl.when(t + 1 < total)
-    def _prefetch_next():
-        dma(jax.lax.rem(t + 1, 2), t + 1).start()
-
-    dma(jax.lax.rem(t, 2), t).wait()
-    x = xbuf_ref[jax.lax.rem(t, 2)].astype(jnp.float32)  # (bs, bp, m)
-    b = b_ref[...].astype(jnp.float32)  # (bs, n, m)
-    y = _dot(x, b, (((2,), (2,)), ((0,), (0,))))
-    w = w_ref[...].astype(jnp.float32)
-    s_tile = _fold_tile_batched(y, w, nonlin)
-    _fold_ybad_tile(y, ybad_ref, i, with_health)
-    _fold_moment_tile(y, mom_ref, i, with_moments)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = s_tile
-
-    @pl.when(i > 0)
-    def _acc():
-        acc_ref[...] += s_tile
-
-    @pl.when(i == n_tiles - 1)
-    def _probe():
-        _probe_streams(
-            b, h_ref, step_ref, gamma_hat_ref, active_ref, conv_ref,
-            conv_out_ref, health_out_ref, moment_out_ref,
-            acc_ref, ybad_ref, mom_ref,
-            with_health=with_health, with_moments=with_moments, blowup=blowup,
-        )
-
-
 def smbgd_probe_bank_pallas(
     X: jnp.ndarray,
     W: jnp.ndarray,
@@ -766,7 +588,6 @@ def smbgd_probe_bank_pallas(
     block_p: int = 512,
     block_s: int = 1,
     interpret: bool = True,
-    prefetch: bool = False,
     health: bool = True,
     moments: bool = False,
     blowup: float = HEALTH_BLOWUP_BOUND,
@@ -781,8 +602,6 @@ def smbgd_probe_bank_pallas(
     and ``moments' (S, 2)`` f32, the raw [Σy², Σy⁴] fold over the probe's Y
     (0 when ``moments=False`` or for masked-out streams).  The state
     operands are read-only: probing never mutates the frozen separators.
-    ``prefetch=True`` double-buffers the X tile DMA (see the step kernel's
-    prefetch notes; bit-identical on the interpret path).
     """
     S, P, m = X.shape
     n = B.shape[1]
@@ -792,45 +611,23 @@ def smbgd_probe_bank_pallas(
     n_tiles = P // block_p
     bs = block_s
     n_sblocks = S // bs
-    common_specs = [
-        pl.BlockSpec((bs, block_p, 1), lambda s, i: (s, i, 0)),
-        pl.BlockSpec((bs, n, m), lambda s, i: (s, 0, 0)),
-        pl.BlockSpec((bs, n, n), lambda s, i: (s, 0, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-    ]
-    if prefetch:
-        kernel = functools.partial(
-            _smbgd_probe_bank_kernel_prefetch,
-            nonlin=nonlinearity, n_tiles=n_tiles, n_sblocks=n_sblocks,
-            block_s=bs, block_p=block_p, with_health=health,
-            with_moments=moments, blowup=blowup,
-        )
-        x_spec = pl.BlockSpec(memory_space=pl.ANY)
-        scratch = [
-            pltpu.VMEM((bs, n, n), jnp.float32),
-            pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
-            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),  # [Σy², Σy⁴] fold
-            pltpu.VMEM((2, bs, block_p, m), X.dtype),  # the double buffer
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-    else:
-        kernel = functools.partial(
-            _smbgd_probe_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
-            with_health=health, with_moments=moments, blowup=blowup,
-        )
-        x_spec = pl.BlockSpec((bs, block_p, m), lambda s, i: (s, i, 0))
-        scratch = [
-            pltpu.VMEM((bs, n, n), jnp.float32),
-            pltpu.VMEM((bs, 1), jnp.int32),
-            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),
-        ]
+    kernel = functools.partial(
+        _smbgd_probe_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
+        with_health=health, with_moments=moments, blowup=blowup,
+    )
     return pl.pallas_call(
         kernel,
         grid=(n_sblocks, n_tiles),
-        in_specs=[x_spec] + common_specs,
+        in_specs=[
+            pl.BlockSpec((bs, block_p, m), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((bs, block_p, 1), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((bs, n, m), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((bs, n, n), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+        ],
         out_specs=[
             pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
             pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
@@ -841,26 +638,15 @@ def smbgd_probe_bank_pallas(
             jax.ShapeDtypeStruct((S, 1), jnp.int32),
             jax.ShapeDtypeStruct((S, MOMENT_LEAVES), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((bs, n, n), jnp.float32),  # gradient accumulator
+            pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
+            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),  # [Σy², Σy⁴] fold
+        ],
         interpret=interpret,
-        name="smbgd_probe_bank",  # one kernel name for both DMA schedules
-        **_compiler_params(prefetch),
+        name="smbgd_probe_bank",
+        compiler_params=_COMPILER_PARAMS,
     )(X, W, B, H_hat, step, gamma_hat, active, conv)
-
-
-def _compiler_params(prefetch: bool) -> dict:
-    """``pallas_call`` kwargs shared by the step and probe megakernels: the
-    explicit VMEM limit the default ``block_s`` is budgeted against
-    (``ops.default_block_s``), and — for the prefetch kernels, whose
-    global-tile prefetch window threads DMA state across grid cells — BOTH
-    grid dimensions sequential ("arbitrary", never "parallel": Mosaic must
-    not megacore-split the grid).  The interpreter ignores both."""
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary") if prefetch else None,
-            vmem_limit_bytes=VMEM_LIMIT_BYTES,
-        )
-    }
 
 
 def smbgd_step_bank_pallas(
@@ -877,7 +663,6 @@ def smbgd_step_bank_pallas(
     block_p: int = 512,
     block_s: int = 1,
     interpret: bool = True,
-    prefetch: bool = False,
     health: bool = True,
     moments: bool = False,
     blowup: float = HEALTH_BLOWUP_BOUND,
@@ -892,10 +677,7 @@ def smbgd_step_bank_pallas(
     through unchanged for frozen streams).  ``block_s`` streams ride one grid
     cell as a batch dimension (S % block_s == 0) — per-stream math is
     independent, so the result is block_s invariant; larger blocks amortize
-    per-cell grid overhead.  ``prefetch=True`` replaces the X BlockSpec
-    pipeline with an explicit double-buffered ``make_async_copy`` from
-    ``pl.ANY`` — overlapping the next tile's DMA with the current fold —
-    and is bit-identical on the interpret path (tested).  ``B``/``H_hat``
+    per-cell grid overhead.  ``B``/``H_hat``
     may live in a reduced-precision storage dtype (bf16): the kernel casts
     to f32 at load, accumulates the gradient and the commit in f32, and
     casts back only at the output writes.  Returns ``(Y (S, P, n), B',
@@ -919,45 +701,23 @@ def smbgd_step_bank_pallas(
     n_tiles = P // block_p
     bs = block_s
     n_sblocks = S // bs
-    common_specs = [
-        pl.BlockSpec((bs, block_p, 1), lambda s, i: (s, i, 0)),
-        pl.BlockSpec((bs, n, m), lambda s, i: (s, 0, 0)),
-        pl.BlockSpec((bs, n, n), lambda s, i: (s, 0, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-        pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
-    ]
-    if prefetch:
-        kernel = functools.partial(
-            _smbgd_step_bank_kernel_prefetch,
-            nonlin=nonlinearity, n_tiles=n_tiles, n_sblocks=n_sblocks,
-            block_s=bs, block_p=block_p, with_health=health,
-            with_moments=moments, blowup=blowup,
-        )
-        x_spec = pl.BlockSpec(memory_space=pl.ANY)
-        scratch = [
-            pltpu.VMEM((bs, n, n), jnp.float32),
-            pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
-            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),  # [Σy², Σy⁴] fold
-            pltpu.VMEM((2, bs, block_p, m), X.dtype),  # the double buffer
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-    else:
-        kernel = functools.partial(
-            _smbgd_step_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
-            with_health=health, with_moments=moments, blowup=blowup,
-        )
-        x_spec = pl.BlockSpec((bs, block_p, m), lambda s, i: (s, i, 0))
-        scratch = [
-            pltpu.VMEM((bs, n, n), jnp.float32),
-            pltpu.VMEM((bs, 1), jnp.int32),
-            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),
-        ]
+    kernel = functools.partial(
+        _smbgd_step_bank_kernel, nonlin=nonlinearity, n_tiles=n_tiles,
+        with_health=health, with_moments=moments, blowup=blowup,
+    )
     return pl.pallas_call(
         kernel,
         grid=(n_sblocks, n_tiles),
-        in_specs=[x_spec] + common_specs,
+        in_specs=[
+            pl.BlockSpec((bs, block_p, m), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((bs, block_p, 1), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((bs, n, m), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((bs, n, n), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+            pl.BlockSpec((bs, 1), lambda s, i: (s, 0)),
+        ],
         out_specs=[
             pl.BlockSpec((bs, block_p, n), lambda s, i: (s, i, 0)),
             pl.BlockSpec((bs, n, m), lambda s, i: (s, 0, 0)),
@@ -976,8 +736,12 @@ def smbgd_step_bank_pallas(
             jax.ShapeDtypeStruct((S, 1), jnp.int32),
             jax.ShapeDtypeStruct((S, MOMENT_LEAVES), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((bs, n, n), jnp.float32),  # gradient accumulator
+            pltpu.VMEM((bs, 1), jnp.int32),  # cross-tile Y-finite fold
+            pltpu.VMEM((bs, MOMENT_LEAVES), jnp.float32),  # [Σy², Σy⁴] fold
+        ],
         interpret=interpret,
-        name="smbgd_step_bank",  # one kernel name for both DMA schedules
-        **_compiler_params(prefetch),
+        name="smbgd_step_bank",
+        compiler_params=_COMPILER_PARAMS,
     )(X, W, B, H_hat, step, gamma_hat, active, conv)

@@ -21,11 +21,8 @@ matches its padded geometry the ``zeros().at[].set()`` staging copy is skipped
 entirely — persistent-layout callers (``stream.SeparatorBank`` in fused mode)
 pay no per-step padding.
 
-Memory-system knobs (PR 6):
+Memory-system knobs:
 
-  * ``prefetch=True`` on the megakernel/probe entry points swaps the X
-    BlockSpec pipeline for an explicit double-buffered ``make_async_copy``
-    (bit-identical on the interpret path),
   * ``bank_layout(dtype_policy="bf16")`` stores persistent ``B``/``Ĥ`` in
     bf16 (f32 accumulation inside the kernels) — ``BankLayout`` owns the
     byte accounting (``persistent_bytes_per_session``,
@@ -294,8 +291,7 @@ def _resident_bytes_per_stream(
     """Conservative VMEM bytes ONE stream holds in a step-megakernel grid
     cell (the probe kernel holds less):
 
-      * every pipelined block twice (Pallas double-buffers them): the X tile
-        (the prefetch kernel's manual double buffer is the same two tiles),
+      * every pipelined block twice (Pallas double-buffers them): the X tile,
         the W rows (a ``(block_p, 1)`` block is lane-padded to 128), the Y
         tile, ``B``/``Ĥ`` in and ``B′``/``Ĥ′`` out at the storage dtype, and
         eight ``(S, 1)``/``(S, 2)`` side-channel rows (lane-padded, 512 B
@@ -393,8 +389,8 @@ def default_block_s(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "nonlinearity", "block_p", "block_s", "interpret", "prefetch",
-        "health", "moments", "blowup",
+        "nonlinearity", "block_p", "block_s", "interpret", "health",
+        "moments", "blowup",
     ),
 )
 def smbgd_step_bank(
@@ -411,7 +407,6 @@ def smbgd_step_bank(
     block_p: int | None = None,
     block_s: int | None = None,
     interpret: bool | None = None,
-    prefetch: bool = False,
     health: bool = True,
     moments: bool = False,
     blowup: float = HEALTH_BLOWUP_BOUND,
@@ -434,8 +429,7 @@ def smbgd_step_bank(
 
     ``block_s`` batches that many streams per grid cell (default: the
     largest divisor of S whose per-cell residency fits the VMEM budget —
-    see ``default_block_s``).  ``prefetch=True`` double-buffers the X tile
-    DMA (bit-identical on the interpret path).  Returns
+    see ``default_block_s``).  Returns
     ``(Y (S, P_pad, n_pad), B', H_hat', step' (S,), conv' (S,),
     health' (S,), moments' (S, 2))`` where ``conv'`` is the relative update
     magnitude ``‖Ĥ′B‖_F/‖B‖_F`` computed inside the commit (see
@@ -496,7 +490,6 @@ def smbgd_step_bank(
             block_p=block_p,
             block_s=block_s,
             interpret=interpret,
-            prefetch=prefetch,
             health=health,
             moments=moments,
             blowup=blowup,
@@ -516,8 +509,8 @@ def smbgd_step_bank(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "nonlinearity", "block_p", "block_s", "interpret", "prefetch",
-        "health", "moments", "blowup",
+        "nonlinearity", "block_p", "block_s", "interpret", "health",
+        "moments", "blowup",
     ),
 )
 def smbgd_probe_bank(
@@ -534,7 +527,6 @@ def smbgd_probe_bank(
     block_p: int | None = None,
     block_s: int | None = None,
     interpret: bool | None = None,
-    prefetch: bool = False,
     health: bool = True,
     moments: bool = False,
     blowup: float = HEALTH_BLOWUP_BOUND,
@@ -598,7 +590,6 @@ def smbgd_probe_bank(
         block_p=block_p,
         block_s=block_s,
         interpret=interpret,
-        prefetch=prefetch,
         health=health,
         moments=moments,
         blowup=blowup,

@@ -1,4 +1,4 @@
-"""Serving: LM decode engine + the drift-aware separation pipeline.
+"""Serving: the drift-aware separation pipeline.
 
 Public API of the separation side (the paper's "deployment in hardware"
 mandate, grown into an end-to-end adaptive service):
@@ -43,12 +43,10 @@ from repro.serve.elastic import AutoscalePolicy, ResizeDecision
 from repro.serve.engine import (
     ConvergenceMonitor,
     ConvergencePolicy,
-    Engine,
     EvictionRecord,
     ParkedSession,
     QuarantinedSession,
     SeparationService,
-    ServeConfig,
     SessionStats,
 )
 from repro.serve.health import HealthEvent, HealthMonitor, HealthPolicy
@@ -79,7 +77,6 @@ __all__ = [
     "DriftEvent",
     "DriftMonitor",
     "DriftPolicy",
-    "Engine",
     "EvictionRecord",
     "HealthEvent",
     "HealthMonitor",
@@ -95,7 +92,6 @@ __all__ = [
     "SLOPolicy",
     "SchedulerContext",
     "SeparationService",
-    "ServeConfig",
     "SessionMeta",
     "SessionStats",
     "TickTimer",

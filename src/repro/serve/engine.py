@@ -1,16 +1,10 @@
-"""Batched serving engines: LM decode + multi-stream separation service.
+"""Multi-stream separation service.
 
-Deployment counterpart of the trainer (the paper's "model creation, training
-AND deployment in hardware" mandate).  Two engines share the
-continuous-batching idiom (slot free-list; new sessions drop into freed slots
-between steps):
-  * ``Engine`` — LM serving: batched requests with per-request lengths,
-    chunked prefill through ``decode_step`` semantics, greedy / temperature
-    sampling,
-  * ``SeparationService`` — ICA serving: admits/evicts separation *sessions*
-    into the slots of a ``repro.stream.SeparatorBank``; every tick steps all
-    live sessions with one fused bank program (the multi-stream analogue of
-    the paper's single always-on FPGA datapath).
+``SeparationService`` admits/evicts separation *sessions* into the slots of
+a ``repro.stream.SeparatorBank`` (continuous batching: a slot free-list, new
+sessions drop into freed slots between ticks); every tick steps all live
+sessions with one fused bank program (the multi-stream analogue of the
+paper's single always-on FPGA datapath).
 
 Session lifecycle state machine (``SeparationService``)::
 
@@ -248,12 +242,7 @@ serving bank's resolved geometry with ``autotune=False``):
     f32 operands regardless of policy — they are compute-side, not
     persistent state.  Worth it on real TPU at scale; on CPU interpret it
     only changes bytes, not speed.
-  * ``prefetch=True`` double-buffers the megakernel's X-tile DMA so the next
-    tile streams in during the current tile's gradient fold.  Turn it on for
-    real TPU deployments (it is where the bandwidth overlap pays); on the
-    interpret path it is bit-identical to the sync path and slightly slower
-    (extra copies), so leave it off for CPU smoke runs.
-  * tile geometry (``block_p``/``block_s``) and ``prefetch`` resolve from the
+  * tile geometry (``block_p``/``block_s``) resolves from the
     persisted autotune cache (``AUTOTUNE.json``, see ``stream.autotune``)
     when left unset — run ``benchmarks/stream_throughput.py --autotune`` on
     the target backend once per deployment shape.  ``dtype_policy`` is never
@@ -272,12 +261,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
 from repro.core import metrics as metrics_lib
 from repro.core import smbgd as smbgd_lib
 from repro.core.smbgd import BankHyperparams, SMBGDState
 from repro.data import sources as sources_lib
-from repro.models import model as M
 from repro.serve.drift import DriftEvent, DriftMonitor, DriftPolicy
 from repro.serve.elastic import AutoscalePolicy, ResizeDecision
 from repro.serve.health import HealthEvent, HealthMonitor, HealthPolicy
@@ -295,8 +282,6 @@ from repro.serve.slo import (
     TickTimer,
 )
 from repro.stream.bank import BankState, SeparatorBank
-
-PyTree = Any
 
 # The phase spans of a tick (module docstring, "Tracing").  Every name has
 # the ``serve.`` prefix, so none collides with a span a client opens around
@@ -319,55 +304,6 @@ SPANS = (
     "serve.autoscale",
 )
 _span = jax.profiler.TraceAnnotation
-
-
-@dataclasses.dataclass
-class ServeConfig:
-    max_batch: int = 8
-    max_len: int = 512
-    temperature: float = 0.0  # 0 → greedy
-    seed: int = 0
-
-
-class Engine:
-    def __init__(self, cfg: ModelConfig, params: PyTree, scfg: ServeConfig):
-        self.cfg = cfg
-        self.params = params
-        self.scfg = scfg
-        self._decode = jax.jit(
-            lambda p, s, b: M.decode_step(p, s, b, cfg)
-        )
-        self.state = M.init_serve_state(cfg, scfg.max_batch, scfg.max_len)
-        self.key = jax.random.PRNGKey(scfg.seed)
-
-    def _sample(self, logits: jnp.ndarray) -> jnp.ndarray:
-        logits = logits[:, -1]  # last position: (B, V), or (B, K, V) w/ codebooks
-        if self.scfg.temperature <= 0:
-            return jnp.argmax(logits, axis=-1)
-        self.key, k = jax.random.split(self.key)
-        return jax.random.categorical(k, logits / self.scfg.temperature, axis=-1)
-
-    def prefill_and_generate(
-        self, prompts: jnp.ndarray, n_new: int
-    ) -> Tuple[jnp.ndarray, List[float]]:
-        """prompts: (B, T_prompt[, K]); returns (B, n_new[, K]) generated
-        tokens (greedy/temperature).  Prefill is token-streamed through the
-        recurrent state machinery — one code path for all families."""
-        B, T = prompts.shape[0], prompts.shape[1]
-        assert B == self.scfg.max_batch
-        state = M.init_serve_state(self.cfg, B, self.scfg.max_len)
-        logits = None
-        for t in range(T):  # chunked prefill (chunk = 1 keeps it family-agnostic)
-            tok = prompts[:, t : t + 1]
-            logits, state = self._decode(self.params, state, {"tokens": tok})
-        out = []
-        tok = self._sample(logits)[:, None] if not self.cfg.n_codebooks else self._sample(logits)[:, None, :]
-        for _ in range(n_new):
-            out.append(tok)
-            logits, state = self._decode(self.params, state, {"tokens": tok})
-            tok = self._sample(logits)[:, None] if not self.cfg.n_codebooks else self._sample(logits)[:, None, :]
-        self.state = state
-        return jnp.concatenate(out, axis=1), []
 
 
 @dataclasses.dataclass
@@ -2082,7 +2018,7 @@ class SeparationService:
     def _probe_bank(self, width: int) -> Tuple[SeparatorBank, Any]:
         """The (cached) transient probe bank of ``width`` slots: same step
         geometry AND memory-system knobs as the serving bank (fused / pallas
-        / block_p / dtype_policy / prefetch) with the bank's base
+        / block_p / dtype_policy) with the bank's base
         hyperparameters — exactly what ``_virtual_conv`` models per session —
         and its jitted no-commit probe step.  ``autotune=False``: the probe
         width is a transient pow-2, not a shape anyone tuned for, so the
@@ -2102,7 +2038,6 @@ class SeparationService:
                     else self.bank.block_p
                 ),
                 dtype_policy=self.bank.dtype_policy,
-                prefetch=bool(self.bank.prefetch),
                 moments=bool(self.bank.moments),
                 autotune=False,
             )
@@ -2151,7 +2086,7 @@ class SeparationService:
     def _step_key(bank: SeparatorBank) -> Tuple:
         """Jitted-step cache key: a resize back to a previously served
         (width, geometry) reuses its compiled program instead of retracing."""
-        return (bank.n_streams, bank.block_p, bank.block_s, bank.prefetch)
+        return (bank.n_streams, bank.block_p, bank.block_s)
 
     def _get_step(self, bank: SeparatorBank):
         got = self._step_cache.get(self._step_key(bank))
@@ -2352,15 +2287,13 @@ class SeparationService:
         # only when the re-resolution actually changed it (stacked-state
         # caches key on park stamps, not geometry, but a probe bank rebuild
         # would re-pad them, so they go together)
-        old_geom = (
-            self.bank.layout.block_p if self.bank.fused else self.bank.block_p,
-            bool(self.bank.prefetch),
+        old_block_p = (
+            self.bank.layout.block_p if self.bank.fused else self.bank.block_p
         )
-        new_geom = (
-            new_bank.layout.block_p if new_bank.fused else new_bank.block_p,
-            bool(new_bank.prefetch),
+        new_block_p = (
+            new_bank.layout.block_p if new_bank.fused else new_bank.block_p
         )
-        if old_geom != new_geom:
+        if old_block_p != new_block_p:
             self._probe_banks = {}
             self._probe_stacks = {}
         self.bank = new_bank

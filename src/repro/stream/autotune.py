@@ -1,12 +1,12 @@
 """Persisted 2-D autotune cache for the bank megakernel.
 
 ``benchmarks/stream_throughput.py --autotune`` sweeps the megakernel's
-``(block_p, block_s)`` tile geometry (also toggling ``prefetch`` and the
-``dtype_policy``) and persists the winning config here, keyed by the problem
+``(block_p, block_s)`` tile geometry (also measuring the ``dtype_policy``)
+and persists the winning config here, keyed by the problem
 shape and backend:
 
     "S=64,P=32,m=4,n=2,backend=cpu-interpret": {
-        "block_p": 32, "block_s": 64, "prefetch": false,
+        "block_p": 32, "block_s": 64,
         "fused_tick_s": ...,            # measured, f32 policy
         "bf16_fused_tick_s": ...,       # same geometry, bf16 storage
         "persistent_bytes_per_session": 1032,
@@ -15,7 +15,7 @@ shape and backend:
     }
 
 ``SeparatorBank`` consults the cache by default (``autotune=True``) for any
-GEOMETRY knob left unset — ``block_p``, ``block_s``, ``prefetch`` — so a
+GEOMETRY knob left unset — ``block_p``, ``block_s`` — so a
 tuned deployment gets the swept tiling without threading numbers by hand.
 ``dtype_policy`` is recorded but NEVER auto-applied: storage precision
 changes results (within tested tolerance, but still), so it stays an
@@ -42,7 +42,7 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 _DEFAULT_PATH = Path(__file__).resolve().parents[3] / "AUTOTUNE.json"
 
 # knobs SeparatorBank may adopt from a cache hit (never dtype_policy)
-GEOMETRY_KEYS = ("block_p", "block_s", "prefetch")
+GEOMETRY_KEYS = ("block_p", "block_s")
 
 # (path, mtime) -> parsed cache; re-read only when the file changes
 _memo: Dict[tuple, dict] = {}

@@ -51,10 +51,9 @@ gradient fold and the commit accumulation in f32, casting only at the
 load/commit boundaries, so separation quality tracks the f32 oracle within
 a tested tolerance while the per-session HBM footprint halves (the
 capacity number: ``bank.layout.persistent_bytes_per_session``).
-``prefetch=True`` double-buffers the megakernel's X tile DMA (bit-identical
-on the interpret path).  Both knobs — plus ``block_p``/``block_s`` — load
-from the persisted autotune cache (``stream.autotune``, ``AUTOTUNE.json``)
-when left unset; ``autotune=False`` opts out.  ``dtype_policy`` is never
+``block_p``/``block_s`` load from the persisted autotune cache
+(``stream.autotune``, ``AUTOTUNE.json``) when left unset;
+``autotune=False`` opts out.  ``dtype_policy`` is never
 auto-applied from the cache (precision is a caller decision).
 
 Checkpointing: ``BankState`` is a plain pytree of arrays (padded or not), so
@@ -216,7 +215,7 @@ class SeparatorBank:
     ``dtype_policy`` ("f32"/"bf16") sets the persistent storage dtype of
     ``B``/``Ĥ`` (accumulation stays f32 everywhere); the default ``None``
     follows ``easi.dtype`` — the legacy contract where a bf16 config stores
-    bf16 state.  ``prefetch`` toggles the megakernel's double-buffered X DMA.
+    bf16 state.
     Geometry knobs left as ``None`` resolve from the persisted autotune cache
     (``AUTOTUNE.json``) unless ``autotune=False``.
 
@@ -244,7 +243,6 @@ class SeparatorBank:
     block_p: Optional[int] = None
     block_s: Optional[int] = None
     dtype_policy: Optional[str] = None  # None → follow easi.dtype
-    prefetch: Optional[bool] = None
     autotune: bool = True
     health_checks: bool = True
     blowup: Optional[float] = None  # None → ops.HEALTH_BLOWUP_BOUND
@@ -272,7 +270,6 @@ class SeparatorBank:
             {
                 "block_p": self.block_p,
                 "block_s": self.block_s,
-                "prefetch": self.prefetch,
             },
         )
         self._resolve_autotune()
@@ -298,7 +295,7 @@ class SeparatorBank:
                     )
 
     def _resolve_autotune(self) -> None:
-        """Fill unset GEOMETRY knobs (block_p/block_s/prefetch) from the
+        """Fill unset GEOMETRY knobs (block_p/block_s) from the
         persisted autotune cache — best-effort, fused banks only.  The
         resolved values become the dataclass fields, so everything derived
         from this bank (sharded local banks, probe banks built by the
@@ -306,11 +303,7 @@ class SeparatorBank:
         against a different shape key."""
         if not (self.fused and self.autotune):
             return
-        if not (
-            self.block_p is None
-            or self.block_s is None
-            or self.prefetch is None
-        ):
+        if self.block_p is not None and self.block_s is not None:
             return
         try:
             from repro.stream import autotune as autotune_lib
@@ -333,8 +326,6 @@ class SeparatorBank:
             and self.n_streams % int(entry["block_s"]) == 0
         ):
             object.__setattr__(self, "block_s", int(entry["block_s"]))
-        if self.prefetch is None and "prefetch" in entry:
-            object.__setattr__(self, "prefetch", bool(entry["prefetch"]))
 
     @property
     def resolved_dtype_policy(self) -> str:
@@ -717,8 +708,7 @@ class SeparatorBank:
         explicit = getattr(
             self,
             "_explicit_geometry",
-            {"block_p": self.block_p, "block_s": self.block_s,
-             "prefetch": self.prefetch},
+            {"block_p": self.block_p, "block_s": self.block_s},
         )
         block_s = explicit["block_s"]
         if block_s is not None and new_S % block_s != 0:
@@ -728,7 +718,6 @@ class SeparatorBank:
             n_streams=new_S,
             block_p=explicit["block_p"],
             block_s=block_s,
-            prefetch=explicit["prefetch"],
         )
 
     def resize_state(self, state: BankState) -> BankState:
@@ -973,7 +962,6 @@ class SeparatorBank:
                 nonlinearity=self.easi.nonlinearity,
                 block_p=lay.block_p,
                 block_s=self.block_s,
-                prefetch=bool(self.prefetch),
                 health=bool(self.health_checks),
                 moments=bool(self.moments),
                 blowup=self.resolved_blowup,
@@ -1041,7 +1029,6 @@ class SeparatorBank:
                 nonlinearity=self.easi.nonlinearity,
                 block_p=lay.block_p,
                 block_s=self.block_s,
-                prefetch=bool(self.prefetch),
                 health=bool(self.health_checks),
                 moments=bool(self.moments),
                 blowup=self.resolved_blowup,

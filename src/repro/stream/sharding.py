@@ -15,7 +15,7 @@ captures, which would silently replicate them and break the local shapes);
 each device rebuilds a local-width bank around its slice.
 
 The local banks inherit the parent's RESOLVED memory-system knobs
-(``dtype_policy``, ``prefetch``, tile geometry) with ``autotune=False`` —
+(``dtype_policy``, tile geometry) with ``autotune=False`` —
 tuning keys on the global shape; per-device re-resolution against the
 local stream count would silently pick a different (possibly untuned)
 geometry on every device.
@@ -74,7 +74,7 @@ def make_sharded_bank_step(
     # (or was opted out) against the global (S, P, m, n) key, and the local
     # bank must not re-resolve against the local-S key (different entry) or
     # re-derive block_s from local_streams vs a cached global block_s that no
-    # longer divides.  dtype_policy/prefetch ride along via replace().
+    # longer divides.  dtype_policy rides along via replace().
     local_block_s = bank.block_s
     if local_block_s is not None and local_streams % local_block_s:
         local_block_s = None  # fall back to the derived default locally
@@ -84,7 +84,6 @@ def make_sharded_bank_step(
         hyperparams=None,
         block_p=bank.layout.block_p if bank.fused else bank.block_p,
         block_s=local_block_s,
-        prefetch=bool(bank.prefetch),
         autotune=False,
     )
     hetero = bank.hyperparams is not None

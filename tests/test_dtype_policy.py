@@ -1,4 +1,4 @@
-"""PR-6 memory system: dtype policy, prefetch, VMEM-derived block_s, and the
+"""PR-6 memory system: dtype policy, VMEM-derived block_s, and the
 persisted autotune cache.
 
 The differential contracts:
@@ -6,8 +6,9 @@ The differential contracts:
   * bf16 storage vs the f32 oracle — same trajectory within checked-in
     Amari/conv tolerances across ragged shapes and all nonlinearities
     (accumulation is f32 either way; only the stored B/Ĥ quantize),
-  * prefetch=True vs prefetch=False — bit-identical on the interpret path
-    (the DMA pipeline reorders copies, never arithmetic),
+  * stream blocks: a bank stepped and probed in blocks of block_s < S
+    streams is bit-identical to one block of all S (per-stream math is
+    independent),
   * the default block_s derives from the layout's actual VMEM residency
     (no hardcoded caps; compiled backends fail loudly when one stream
     can't fit),
@@ -166,63 +167,72 @@ class TestBf16VsF32Oracle:
         )
 
 
-class TestPrefetchBitIdentity:
+class TestStreamBlockBitIdentity:
+    """The chip runs many stream blocks per launch (64 in the 256-channel
+    cell), while CPU tests mostly run one: a bank split into blocks of
+    ``block_s < S`` streams must give the bits of one block of all S."""
+
     @pytest.mark.parametrize("policy", [None, "bf16"])
     def test_step_bit_identical(self, policy):
-        """prefetch=True reorders the X DMA, never arithmetic: every output
-        of the megakernel step is bit-identical to the sync path."""
+        """Three steps at block_s 1 and 2 against block_s = S = 4: every
+        output, moments included, bit for bit."""
         ecfg, ocfg = _cfgs(P=13, n=3, m=5)
         S = 4
         key = jax.random.PRNGKey(4)
         X = jax.random.normal(jax.random.fold_in(key, 1), (S, 13, 5))
-        mk = lambda pf: SeparatorBank(
-            ecfg, ocfg, S, fused=True, dtype_policy=policy,
-            prefetch=pf, autotune=False,
+        mk = lambda bs: SeparatorBank(
+            ecfg, ocfg, S, fused=True, dtype_policy=policy, block_s=bs,
+            moments=True, autotune=False,
         )
-        sync, pre = mk(False), mk(True)
-        st0 = sync.init(key)
-        st_s, Y_s = sync.step(st0, X)
-        st_p, Y_p = pre.step(st0, X)
-        for a, b in zip(st_s, st_p):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(np.asarray(Y_s), np.asarray(Y_p))
+        whole = mk(S)
+        st0 = whole.init(key)
+        for bs in (1, 2):
+            split = mk(bs)
+            st_w, st_b = st0, st0
+            for _ in range(3):
+                st_w, Y_w = whole.step(st_w, X)
+                st_b, Y_b = split.step(st_b, X)
+                for a, b in zip(st_w, st_b):
+                    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+                np.testing.assert_array_equal(np.asarray(Y_w), np.asarray(Y_b))
 
     @pytest.mark.parametrize("policy", [None, "bf16"])
     def test_probe_bit_identical(self, policy):
+        """A masked probe at block_s 1 and 2 against block_s = S = 4."""
         ecfg, ocfg = _cfgs(P=13, n=3, m=5)
         S = 4
         key = jax.random.PRNGKey(5)
         X = jax.random.normal(jax.random.fold_in(key, 1), (S, 13, 5))
-        mk = lambda pf: SeparatorBank(
-            ecfg, ocfg, S, fused=True, dtype_policy=policy,
-            prefetch=pf, autotune=False,
+        mk = lambda bs: SeparatorBank(
+            ecfg, ocfg, S, fused=True, dtype_policy=policy, block_s=bs,
+            moments=True, autotune=False,
         )
-        sync, pre = mk(False), mk(True)
-        st0 = sync.init(key)
-        st0, _ = sync.step(st0, X)
+        whole = mk(S)
+        st0, _ = whole.step(whole.init(key), X)
         active = jnp.asarray([1, 0, 1, 1], jnp.int32)  # mask crosses blocks
-        conv_s, health_s, _mom_s = sync.probe(st0, X, active=active)
-        conv_p, health_p, _mom_p = pre.probe(st0, X, active=active)
-        np.testing.assert_array_equal(np.asarray(conv_s), np.asarray(conv_p))
-        np.testing.assert_array_equal(np.asarray(health_s), np.asarray(health_p))
+        want = whole.probe(st0, X, active=active)
+        for bs in (1, 2):
+            got = mk(bs).probe(st0, X, active=active)
+            for a, b in zip(want, got):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_prefetch_crosses_stream_block_boundaries(self):
-        """block_s < S forces the pipeline's global tile counter across
-        stream-block boundaries — the warmup/steady-state handoff the DMA
-        slots must survive."""
+    def test_stream_blocks_over_several_p_tiles(self):
+        """S=6 in blocks of 2 with four P-tiles each (block_p 8 of P 32):
+        the accumulator re-initialises at every stream block's first tile
+        and commits at its last."""
         ecfg, ocfg = _cfgs(P=32)
         S = 6
         key = jax.random.PRNGKey(6)
         X = jax.random.normal(jax.random.fold_in(key, 1), (S, 32, 4))
-        mk = lambda pf: SeparatorBank(
-            ecfg, ocfg, S, fused=True, block_p=8, block_s=2,
-            prefetch=pf, autotune=False,
+        mk = lambda bs: SeparatorBank(
+            ecfg, ocfg, S, fused=True, block_p=8, block_s=bs, autotune=False,
         )
-        st0 = mk(False).init(key)
-        st_s, Y_s = mk(False).step(st0, X)
-        st_p, Y_p = mk(True).step(st0, X)
-        np.testing.assert_array_equal(np.asarray(st_s.B), np.asarray(st_p.B))
-        np.testing.assert_array_equal(np.asarray(Y_s), np.asarray(Y_p))
+        st0 = mk(S).init(key)
+        st_w, Y_w = mk(S).step(st0, X)
+        st_b, Y_b = mk(2).step(st0, X)
+        for a, b in zip(st_w, st_b):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(Y_w), np.asarray(Y_b))
 
 
 class TestVmemDerivedBlockS:
@@ -236,12 +246,12 @@ class TestVmemDerivedBlockS:
         monkeypatch.setenv(easi_ops._VMEM_BUDGET_ENV, str(64 * resident))
         assert easi_ops.default_block_s(8, lay, interpret=True) == 8
 
-    def test_prefetch_residency_costs_block_s(self, monkeypatch):
-        """The X double buffer counts against the budget.  Both kernels hold
-        two X tiles per stream (the sync pipeline double-buffers its block,
-        the prefetch kernel its scratch), so one count covers both: the
-        residency grows by two X tiles per extra row block of ``block_p``,
-        and a budget one X tile short of four streams derives block_s 2."""
+    def test_x_double_buffer_costs_block_s(self, monkeypatch):
+        """The X double buffer counts against the budget.  The kernel's
+        block pipeline holds two X tiles per stream, the one being folded
+        and the next one in flight: the residency grows by two X tiles per
+        extra row block of ``block_p``, and a budget one X tile short of four
+        streams derives block_s 2."""
         narrow = easi_ops.bank_layout(2, 4, 32, block_p=8)
         wide = easi_ops.bank_layout(2, 4, 32, block_p=16)
         x_tile = 8 * wide.m_pad * 4  # 8 more rows of one f32 X tile
@@ -294,7 +304,7 @@ class TestAutotuneCache:
         return path
 
     def test_store_lookup_roundtrip(self, monkeypatch, tmp_path):
-        entry = {"block_p": 8, "block_s": 2, "prefetch": True}
+        entry = {"block_p": 8, "block_s": 2}
         self._seed_cache(monkeypatch, tmp_path, entry)
         assert autotune_lib.lookup(4, 8, 4, 2) == entry
         assert autotune_lib.lookup(5, 8, 4, 2) is None  # different shape key
@@ -304,32 +314,30 @@ class TestAutotuneCache:
     def test_bank_resolves_geometry_from_cache(self, monkeypatch, tmp_path):
         self._seed_cache(
             monkeypatch, tmp_path,
-            {"block_p": 8, "block_s": 2, "prefetch": True, "dtype_policy": "bf16"},
+            {"block_p": 8, "block_s": 2, "dtype_policy": "bf16"},
         )
         ecfg, ocfg = _cfgs()
         bank = SeparatorBank(ecfg, ocfg, 4, fused=True)
-        assert (bank.block_p, bank.block_s, bank.prefetch) == (8, 2, True)
+        assert (bank.block_p, bank.block_s) == (8, 2)
         # dtype_policy is recorded but NEVER auto-applied
         assert bank.dtype_policy is None
         assert bank.storage_dtype == jnp.float32
 
     def test_explicit_knobs_and_opt_out_win(self, monkeypatch, tmp_path):
         self._seed_cache(
-            monkeypatch, tmp_path, {"block_p": 8, "block_s": 2, "prefetch": True}
+            monkeypatch, tmp_path, {"block_p": 8, "block_s": 2}
         )
         ecfg, ocfg = _cfgs()
         explicit = SeparatorBank(ecfg, ocfg, 4, fused=True, block_p=16)
         assert explicit.block_p == 16  # explicit beats cached
         assert explicit.block_s == 2  # unset knobs still fill in
         opt_out = SeparatorBank(ecfg, ocfg, 4, fused=True, autotune=False)
-        assert (opt_out.block_p, opt_out.block_s, opt_out.prefetch) == (
-            None, None, None,
-        )
+        assert (opt_out.block_p, opt_out.block_s) == (None, None)
 
     def test_non_dividing_cached_block_s_skipped(self, monkeypatch, tmp_path):
         self._seed_cache(
             monkeypatch, tmp_path,
-            {"block_p": 8, "block_s": 3, "prefetch": False},
+            {"block_p": 8, "block_s": 3},
         )
         ecfg, ocfg = _cfgs()
         bank = SeparatorBank(ecfg, ocfg, 4, fused=True)  # 4 % 3 != 0
@@ -360,7 +368,7 @@ class TestAutotuneCache:
         st0 = default.init(key)
         st_d, Y_d = default.step(st0, X)
         self._seed_cache(
-            monkeypatch, tmp_path, {"block_p": 8, "block_s": 2, "prefetch": True}
+            monkeypatch, tmp_path, {"block_p": 8, "block_s": 2}
         )
         tuned = SeparatorBank(ecfg, ocfg, 4, fused=True)
         st_t, Y_t = tuned.step(st0, X)
@@ -373,41 +381,35 @@ class TestAutotuneCache:
         (S, P, m, n, backend): each width adopts its own tuned geometry."""
         self._seed_cache(
             monkeypatch, tmp_path,
-            {"block_p": 8, "block_s": 2, "prefetch": False}, S=4,
+            {"block_p": 8, "block_s": 2}, S=4,
         )
-        autotune_lib.store(8, 8, 4, 2, {
-            "block_p": 4, "block_s": 4, "prefetch": True,
-        })
+        autotune_lib.store(8, 8, 4, 2, {"block_p": 4, "block_s": 4})
         ecfg, ocfg = _cfgs()
         bank = SeparatorBank(ecfg, ocfg, 4, fused=True)
-        assert (bank.block_p, bank.block_s, bank.prefetch) == (8, 2, False)
+        assert (bank.block_p, bank.block_s) == (8, 2)
         wide = bank.with_streams(8)
-        assert (wide.block_p, wide.block_s, wide.prefetch) == (4, 4, True)
+        assert (wide.block_p, wide.block_s) == (4, 4)
         # and back: the original width's entry re-adopts, not the wide one's
         back = wide.with_streams(4)
-        assert (back.block_p, back.block_s, back.prefetch) == (8, 2, False)
+        assert (back.block_p, back.block_s) == (8, 2)
 
     def test_resize_keeps_explicit_knobs_winning(self, monkeypatch, tmp_path):
         self._seed_cache(
             monkeypatch, tmp_path,
-            {"block_p": 8, "block_s": 2, "prefetch": False}, S=4,
+            {"block_p": 8, "block_s": 2}, S=4,
         )
-        autotune_lib.store(8, 8, 4, 2, {
-            "block_p": 4, "block_s": 4, "prefetch": True,
-        })
+        autotune_lib.store(8, 8, 4, 2, {"block_p": 4, "block_s": 4})
         ecfg, ocfg = _cfgs()
         bank = SeparatorBank(ecfg, ocfg, 4, fused=True, block_p=16)
         assert bank.block_p == 16 and bank.block_s == 2
         wide = bank.with_streams(8)
         # the hand-set knob survives the resize; unset knobs re-resolve
         assert wide.block_p == 16
-        assert (wide.block_s, wide.prefetch) == (4, True)
+        assert wide.block_s == 4
         # opt-out stays opted out at every width
         opt_out = SeparatorBank(ecfg, ocfg, 4, fused=True, autotune=False)
         wide_out = opt_out.with_streams(8)
-        assert (wide_out.block_p, wide_out.block_s, wide_out.prefetch) == (
-            None, None, None,
-        )
+        assert (wide_out.block_p, wide_out.block_s) == (None, None)
 
     def test_resize_with_missing_or_corrupt_cache_falls_back(
         self, monkeypatch, tmp_path
@@ -416,24 +418,20 @@ class TestAutotuneCache:
         the VMEM-budget derived defaults — and the resized bank still steps."""
         self._seed_cache(
             monkeypatch, tmp_path,
-            {"block_p": 8, "block_s": 2, "prefetch": True}, S=4,
+            {"block_p": 8, "block_s": 2}, S=4,
         )
         ecfg, ocfg = _cfgs()
         bank = SeparatorBank(ecfg, ocfg, 4, fused=True)
         assert bank.block_p == 8
         wide = bank.with_streams(8)  # no S=8 entry seeded
-        assert (wide.block_p, wide.block_s, wide.prefetch) == (
-            None, None, None,
-        )
+        assert (wide.block_p, wide.block_s) == (None, None)
         key = jax.random.PRNGKey(0)
         X = jax.random.normal(jax.random.fold_in(key, 1), (8, 8, 4))
         wide.step(wide.init(key), X)  # budget-derived geometry serves
         # corrupt cache mid-flight: the resize itself must not raise
         (tmp_path / "autotune.json").write_text("{not json")
         narrow = wide.with_streams(2)
-        assert (narrow.block_p, narrow.block_s, narrow.prefetch) == (
-            None, None, None,
-        )
+        assert (narrow.block_p, narrow.block_s) == (None, None)
 
     def test_checked_in_cache_parses_and_keys_well_formed(self):
         """The committed AUTOTUNE.json artifact stays loadable and every

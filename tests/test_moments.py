@@ -5,7 +5,7 @@ Three layers under test:
   * the kernel fold — ``ops.smbgd_step_bank(moments=True)`` /
     ``smbgd_probe_bank(moments=True)`` against the naive ``moments_ref``
     whole-array oracle and the vmap bank path, across ragged shapes, every
-    nonlinearity, both storage dtypes and both DMA schedules — plus the
+    nonlinearity, both storage dtypes and several stream blocks — plus the
     bit-identity contract: ``moments`` is purely observational, every other
     output is unchanged by it,
   * the host-side ``MomentController`` — EMA kurtosis → μ multiplier with
@@ -130,12 +130,11 @@ class TestKernelMoments:
         P=st.sampled_from([8, 16]),
         nonlinearity=st.sampled_from(sorted(NONLINEARITIES)),
         dtype=st.sampled_from(["f32", "bf16"]),
-        prefetch=st.sampled_from([False, True]),
     )
     @settings(max_examples=20, deadline=None)
-    def test_property_sweep(self, S, shape, P, nonlinearity, dtype, prefetch):
-        """Fused fold ≡ naive oracle across ragged shapes, nonlinearities,
-        storage dtypes and DMA schedules."""
+    def test_property_sweep(self, S, shape, P, nonlinearity, dtype):
+        """Fused fold ≡ naive oracle across ragged shapes, nonlinearities
+        and storage dtypes."""
         n, m = shape
         state_dtype = jnp.float32 if dtype == "f32" else jnp.bfloat16
         lay, args = _padded_inputs(
@@ -145,7 +144,6 @@ class TestKernelMoments:
             *args,
             nonlinearity=nonlinearity,
             block_p=lay.block_p,
-            prefetch=prefetch,
             moments=True,
         )
         *_refs, mom_ref = smbgd_step_bank_ref(
@@ -181,18 +179,20 @@ class TestMomentsBitIdentity:
             np.asarray(off[-1]), np.zeros((3, 2), np.float32)
         )
 
-    def test_sync_prefetch_bit_identical(self):
-        """The double-buffered DMA schedule reorders nothing arithmetic —
-        moments included (the interpret path is bit-exact)."""
+    def test_moments_identical_across_block_s(self):
+        """The moment fold is per stream: splitting the bank into stream
+        blocks of 1 or 2 streams changes no bit of any output, moments
+        included (the chip runs many stream blocks per launch)."""
         lay, args = _padded_inputs(4, 16, 2, 4, jax.random.PRNGKey(9))
-        sync = easi_ops.smbgd_step_bank(
-            *args, block_p=lay.block_p, prefetch=False, moments=True
+        whole = easi_ops.smbgd_step_bank(
+            *args, block_p=lay.block_p, block_s=4, moments=True
         )
-        pref = easi_ops.smbgd_step_bank(
-            *args, block_p=lay.block_p, prefetch=True, moments=True
-        )
-        for a, b in zip(sync, pref):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for block_s in (1, 2):
+            split = easi_ops.smbgd_step_bank(
+                *args, block_p=lay.block_p, block_s=block_s, moments=True
+            )
+            for a, b in zip(whole, split):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_bank_step_identical_with_moments(self):
         """Bank layer: a ``moments=True`` bank commits the identical state

@@ -1,6 +1,10 @@
 """Serving engine: deterministic greedy generation, family coverage, the
 adaptive-ICA deployment loop (the paper's streaming use-case)."""
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -11,13 +15,9 @@ from repro.configs.registry import get_config
 from repro.core import AdaptiveICA, EASIConfig, SMBGDConfig, amari_index, global_system
 from repro.core import smbgd as smbgd_lib
 from repro.data.pipeline import MixedSignals
+from repro.launch.serve import Engine, ServeConfig
 from repro.models import model as M
-from repro.serve.engine import (
-    ConvergencePolicy,
-    Engine,
-    SeparationService,
-    ServeConfig,
-)
+from repro.serve.engine import ConvergencePolicy, SeparationService
 from repro.stream import SeparatorBank
 from _hypothesis_compat import given, settings, st
 
@@ -98,6 +98,23 @@ class TestSampling:
         np.testing.assert_array_equal(
             np.asarray(tok), np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         )
+
+
+def test_separation_service_imports_no_lm_code():
+    """``repro.serve`` loads neither the model zoo nor the flash-attention
+    kernel: the LM engine lives in ``repro.launch.serve``.  A fresh
+    interpreter, since this suite imports both."""
+    code = (
+        "import sys, repro.serve; print(sorted(m for m in sys.modules if "
+        "m.startswith(('repro.models', 'repro.kernels.flash_attention'))))"
+    )
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, check=True, timeout=120,
+    )
+    assert out.stdout.strip() == "[]"
 
 
 class TestSeparationService:

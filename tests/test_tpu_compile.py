@@ -67,8 +67,8 @@ def _kernel_args(sharding, S, lay):
     )
 
 
-def _compile_kernel(topo, kind, S, *, prefetch=False, policy="f32",
-                    n=EASI.n_components, m=EASI.n_features, nonlinearity="cubic"):
+def _compile_kernel(topo, kind, S, *, policy="f32", n=EASI.n_components,
+                    m=EASI.n_features, nonlinearity="cubic"):
     """Compile one megakernel (the paper shape unless told) with the
     default block_s; returns (compiled, resolved block_s)."""
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -77,21 +77,27 @@ def _compile_kernel(topo, kind, S, *, prefetch=False, policy="f32",
     )
     fn = easi_ops.smbgd_step_bank if kind == "step" else easi_ops.smbgd_probe_bank
     call = lambda *a: fn(
-        *a, block_p=lay.block_p, interpret=False, prefetch=prefetch,
-        moments=True, nonlinearity=nonlinearity,
+        *a, block_p=lay.block_p, interpret=False, moments=True,
+        nonlinearity=nonlinearity,
     )
     compiled = jax.jit(call).lower(*_kernel_args(one_chip, S, lay)).compile()
     return compiled, easi_ops.default_block_s(S, lay, interpret=False)
 
 
+@pytest.mark.parametrize(
+    "shape",
+    [(4, 2, "cubic"), (22, 22, "tanh")],
+    ids=["paper", "eeg22"],
+)
 @pytest.mark.parametrize("policy", ["f32", "bf16"])
-@pytest.mark.parametrize("prefetch", [False, True], ids=["sync", "prefetch"])
 @pytest.mark.parametrize("kind", ["step", "probe"])
-def test_megakernel_compiles_at_default_block_s(topo, kind, prefetch, policy):
-    """Step and probe, sync and prefetch, f32 and bf16 state: at S=1024 the
-    default block_s compiles under the kernels' VMEM limit."""
+def test_megakernel_compiles_at_default_block_s(topo, kind, policy, shape):
+    """Step and probe, f32 and bf16 state, at the paper's shape and the
+    22-channel EEG cell's: at S=1024 the default block_s compiles under the
+    kernels' VMEM limit."""
+    m, n, nonlinearity = shape
     compiled, block_s = _compile_kernel(
-        topo, kind, 1024, prefetch=prefetch, policy=policy
+        topo, kind, 1024, policy=policy, n=n, m=m, nonlinearity=nonlinearity
     )
     assert "tpu_custom_call" in compiled.as_text()
     assert block_s % 8 == 0 and 1024 % block_s == 0
@@ -105,15 +111,20 @@ def test_width_not_a_multiple_of_8_compiles(topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_step_megakernel_compiles_at_256_lanes(topo):
-    """The high-density EEG cell's step: 512 sessions at m = n = 256, tanh.
-    Nothing is padded, a stream holds about 5.4 MB of VMEM, so the default
-    block_s is 8 (64 grid cells over the streams) under the 64 MiB limit,
-    and the kernel runs in place of any HBM temporary."""
+@pytest.mark.parametrize("policy", ["f32", "bf16"])
+@pytest.mark.parametrize("kind", ["step", "probe"])
+def test_megakernel_compiles_at_256_lanes(topo, kind, policy):
+    """The high-density EEG cell's step, and the probe its health ladder
+    compiles: 512 sessions at m = n = 256, tanh.  Nothing is padded, a
+    stream holds about 5.4 MB of VMEM, so the default block_s is 8 (64 grid
+    cells over the streams) under the 64 MiB limit, and the kernel runs in
+    place of any HBM temporary."""
     compiled, block_s = _compile_kernel(
-        topo, "step", 512, n=256, m=256, nonlinearity="tanh"
+        topo, kind, 512, policy=policy, n=256, m=256, nonlinearity="tanh"
     )
-    lay = easi_ops.bank_layout(256, 256, SMBGD.batch_size, interpret=False)
+    lay = easi_ops.bank_layout(
+        256, 256, SMBGD.batch_size, interpret=False, dtype_policy=policy
+    )
     assert (lay.n_pad, lay.m_pad) == (256, 256)
     assert block_s == 8
     assert "tpu_custom_call" in compiled.as_text()
@@ -146,18 +157,17 @@ def test_sharded_step_compiles_without_collectives(topo, compiled_lowering):
     assert not [c for c in COLLECTIVES if c in hlo]
 
 
-@pytest.mark.parametrize("prefetch", [False, True], ids=["sync", "prefetch"])
 @pytest.mark.parametrize("kind", ["step", "probe"])
-def test_megakernel_carries_one_name_for_both_schedules(topo, kind, prefetch):
-    """The kernel's name is fixed, not taken from the kernel function that
-    the DMA schedule picks: a trace finds it by that name either way.
-    Lowered, not compiled: the HLO keeps each instruction's ``op_name``."""
+def test_megakernel_carries_one_name(topo, kind):
+    """The kernel's name is fixed, not taken from the kernel function: a
+    trace finds it by that name.  Lowered, not compiled: the HLO keeps each
+    instruction's ``op_name``."""
     lay = easi_ops.bank_layout(
         EASI.n_components, EASI.n_features, SMBGD.batch_size, interpret=False
     )
     fn = easi_ops.smbgd_step_bank if kind == "step" else easi_ops.smbgd_probe_bank
     call = lambda *a: fn(
-        *a, block_p=lay.block_p, interpret=False, prefetch=prefetch, moments=True
+        *a, block_p=lay.block_p, interpret=False, moments=True
     )
     args = _kernel_args(SingleDeviceSharding(topo.devices[0]), 64, lay)
     hlo = jax.jit(call).lower(*args).as_text(dialect="hlo", debug_info=True)
